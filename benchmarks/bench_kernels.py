@@ -54,12 +54,6 @@ def run():
     flops = 2 * 128 * 8192 * 96
     emit("kernel/distance_ip_128x8192x96", t * 1e6, f"gflops={flops/t/1e9:.1f}")
     out["distance"] = t
-    # pq adc
-    lut = jnp.asarray(rng.standard_normal((128, 8, 256)), jnp.float32)
-    codes = jnp.asarray(rng.integers(0, 256, (8192, 8)), jnp.int32)
-    t = _time(lambda a, b: ops.pq_adc(a, b, impl="xla"), lut, codes)
-    emit("kernel/pq_adc_128x8192x8x256", t * 1e6, f"lookups_per_s={128*8192*8/t:.2e}")
-    out["pq_adc"] = t
     # flash attention fwd
     qq = jnp.asarray(rng.standard_normal((1, 1024, 8, 64)), jnp.float32)
     kk = jnp.asarray(rng.standard_normal((1, 1024, 4, 64)), jnp.float32)

@@ -20,7 +20,9 @@ from repro.tuning.serve_tuner import ServeTuningEnv, make_serving_space  # noqa:
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     cfg = dataclasses.replace(
         reduce(get_arch("glm4-9b")), name="tune-target", d_model=256, n_heads=8,
         n_kv_heads=4, head_dim=32, d_ff=512, vocab=1024, n_layers=4,

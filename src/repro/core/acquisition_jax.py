@@ -14,7 +14,7 @@ Numerics
 --------
 The GP math runs in float32 with the same operation sequence as
 ``gp._predict_padded`` / ``gp._append_rows``; acquisition scores are then
-computed in float64 (under a local ``jax.experimental.enable_x64`` scope)
+computed in float64 (under a local ``jax.enable_x64(True)`` scope)
 exactly like the numpy path, which does float64 scoring on the float32
 posterior. Selected indices are argmax-equivalent to the numpy path up to
 reduction-order rounding (~1e-12 relative on the scores) — seeded q=1/q=4
@@ -34,7 +34,6 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .gp import _JITTER, _NOISE_FLOOR, matern52
 
@@ -237,7 +236,7 @@ def fused_qehvi_select(gp, Xc: np.ndarray, front: np.ndarray, ref: np.ndarray,
     eps = np.stack([rng.standard_normal((n_samples, Xc.shape[0], 2)) for _ in range(q)])
     fp, fm = _padded_front(np.asarray(front, np.float64).reshape(-1, 2), q)
     ops = _gp_operands(gp, q)
-    with enable_x64():
+    with jax.enable_x64(True):
         picks = _fused_qehvi(
             *ops, jnp.asarray(np.asarray(Xc, np.float32)), fp, fm,
             np.asarray(ref, np.float64), eps, q=q,
@@ -251,7 +250,7 @@ def fused_cei_select(gp, Xc: np.ndarray, best_feasible: float, rlim_n: float,
     """Device-resident sequential-greedy constrained-EI batch selection."""
     q = min(int(q), Xc.shape[0])
     ops = _gp_operands(gp, q)
-    with enable_x64():
+    with jax.enable_x64(True):
         picks = _fused_cei(
             *ops, jnp.asarray(np.asarray(Xc, np.float32)),
             np.float64(best_feasible), np.float64(rlim_n), q=q,

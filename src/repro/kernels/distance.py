@@ -21,7 +21,8 @@ the block multiple on every tiled axis by the host-side wrapper — padded
 query rows produce garbage rows that the wrapper slices off, padded D
 columns contribute zero to the contraction, and padded N columns are cut by
 the final slice. All accumulation is f32 in VMEM scratch regardless of the
-storage dtype.
+storage dtype, and the contraction runs at ``Precision.HIGHEST`` like the
+XLA reference in ``ref.py``.
 """
 from __future__ import annotations
 
@@ -40,8 +41,12 @@ def _dist_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, acc_ref, *, kind: str, k_s
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    q, x = q_ref[...], x_ref[...]
+    if q.dtype != x.dtype:  # Mosaic contracts one dtype: f32 queries, bf16 storage
+        q, x = q.astype(jnp.float32), x.astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
-        q_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
+        q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None,
         preferred_element_type=jnp.float32,
     )
 

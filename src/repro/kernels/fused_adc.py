@@ -9,16 +9,15 @@ the in-kernel probe and running-top-k stages); the scoring stage differs:
   composed path uses), summed over m in the same order so scores are
   bit-identical to the composed scan.
 * :func:`fused_ivf_pq_topk_pallas` — TPU kernel: no gather on TPU, so each
-  code tile scores via m one-hot matmuls against the VMEM-resident LUT
-  (the :mod:`repro.kernels.pq_adc` adaptation), then flows through the
-  shared membership-mask + running-top-k stages.
+  code tile scores via m one-hot matmuls against the query block's LUT,
+  then flows through the shared membership-mask + running-top-k stages.
 
 Memory-layout contract
 ----------------------
-* Codes are passed TRANSPOSED to the kernel — (m, s) int32, row-major — so
-  the tiled axis (s) is the lane axis; the LUT is padded per subquantizer to
-  a 128-multiple code width and flattened to (B, m*cpad), zero-padded slots
-  are never matched because codes < c.
+* Codes are passed TRANSPOSED to the kernel — (n_seg, m, s) int32,
+  row-major — so the tiled axis (s) is the lane axis; the LUT is padded per
+  subquantizer to a 128-multiple code width and flattened to (B, m*cpad),
+  zero-padded slots are never matched because codes < c.
 * Everything else follows fused_scan: zero-pad to block multiples, padding
   masked via ``cluster_of == -1``, f32 accumulation, (B, k) outputs with
   -1/-inf empty slots and impl-defined tie ordering.
@@ -33,10 +32,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .fused_scan import (
+    HIGHEST,
     _round_up,
     merge_tile_topk,
     probe_and_init,
     probe_candidates,
+    stacked_layout,
+    stacked_spec,
     topk_candidates,
 )
 
@@ -45,13 +47,13 @@ def _fused_pq_kernel(
     q_ref, c_ref, lut_ref, codes_ref, cl_ref, gid_ref, lid_out, sim_out,
     cmask_scr, vals_scr, lids_scr, *, nlist, nprobe, k, m, cpad, n_steps, mask_dead,
 ):
-    j = pl.program_id(0)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         probe_and_init(q_ref, c_ref, cmask_scr, vals_scr, lids_scr, nlist=nlist, nprobe=nprobe)
 
-    bp = lut_ref.shape[0]
+    bq = lut_ref.shape[0]
     bn = codes_ref.shape[1]
 
     def body(mi, acc):
@@ -59,12 +61,13 @@ def _fused_pq_kernel(
         onehot = (
             jax.lax.broadcasted_iota(jnp.int32, (cpad, bn), 0) == crow
         ).astype(jnp.float32)  # (cpad, bn)
-        lutm = lut_ref[:, pl.ds(mi * cpad, cpad)]  # (Bp, cpad)
+        lutm = lut_ref[:, pl.ds(pl.multiple_of(mi * cpad, 128), cpad)]  # (bq, cpad)
         return acc + jax.lax.dot_general(
-            lutm, onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            lutm, onehot, (((1,), (0,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32,
         )
 
-    scores = jax.lax.fori_loop(0, m, body, jnp.zeros((bp, bn), jnp.float32))
+    scores = jax.lax.fori_loop(0, m, body, jnp.zeros((bq, bn), jnp.float32))
     merge_tile_topk(
         scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, k=k, mask_dead=mask_dead
     )
@@ -75,7 +78,9 @@ def _fused_pq_kernel(
         sim_out[...] = vals_scr[...]
 
 
-@functools.partial(jax.jit, static_argnames=("nprobe", "k", "mask_dead", "bn", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("nprobe", "k", "mask_dead", "bq", "bn", "interpret")
+)
 def fused_ivf_pq_topk_pallas(
     q: jnp.ndarray,
     lut: jnp.ndarray,
@@ -87,27 +92,29 @@ def fused_ivf_pq_topk_pallas(
     nprobe: int,
     k: int,
     mask_dead: bool = False,
+    bq: int = 128,
     bn: int = 256,
     interpret: bool = False,
 ):
-    """One segment: q (B, d) f32, lut (B, m, c) f32, codes (s, m) integer,
-    centroids (nlist, d), cluster_of (s,), gids (s,) -> (lids, sims) (B, k)."""
+    """Stacked segments in one kernel: q (B, d) f32, lut (B, m, c) f32, codes
+    (n_seg, s, m) integer, centroids (n_seg, nlist, d), cluster_of
+    (n_seg, s), gids (n_seg, s) -> (lids, sims) each (n_seg, B, k). Grid
+    ``(segment, query block, segment tile)`` as in the SQ8 kernel."""
     b, d = q.shape
     _, m, c = lut.shape
-    s = codes.shape[0]
-    nlist = centroids.shape[0]
-    bp, dp, lp = _round_up(b, 8), _round_up(d, 128), _round_up(nlist, 128)
-    cpad = _round_up(c, 128)
-    bn = min(bn, _round_up(s, 128))
-    np_ = _round_up(s, bn)
-    kp = _round_up(k, 128)
+    n_seg, s, _ = codes.shape
+    nlist = centroids.shape[1]
+    bq, bp, bn, np_, lp, kp = stacked_layout(b, s, nlist, k, bq, bn)
+    dp, cpad = _round_up(d, 128), _round_up(c, 128)
     qp = jnp.pad(q.astype(jnp.float32), ((0, bp - b), (0, dp - d)))
-    cp = jnp.pad(centroids.astype(jnp.float32), ((0, lp - nlist), (0, dp - d)))
+    cp = jnp.pad(centroids.astype(jnp.float32), ((0, 0), (0, lp - nlist), (0, dp - d)))
     lutp = jnp.pad(lut.astype(jnp.float32), ((0, bp - b), (0, 0), (0, cpad - c)))
     lutp = lutp.reshape(bp, m * cpad)
-    codes_t = jnp.pad(codes.astype(jnp.int32), ((0, np_ - s), (0, 0)), constant_values=-1).T
-    clp = jnp.pad(cluster_of.astype(jnp.int32), (0, np_ - s), constant_values=-1)
-    gp = jnp.pad(gids.astype(jnp.int32), (0, np_ - s), constant_values=-1)
+    codes_t = jnp.pad(
+        codes.astype(jnp.int32), ((0, 0), (0, np_ - s), (0, 0)), constant_values=-1
+    ).transpose(0, 2, 1)  # (n_seg, m, np_)
+    clp = jnp.pad(cluster_of.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
+    gp = jnp.pad(gids.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
     n_steps = np_ // bn
 
     lids, sims = pl.pallas_call(
@@ -121,31 +128,34 @@ def fused_ivf_pq_topk_pallas(
             n_steps=n_steps,
             mask_dead=mask_dead,
         ),
-        grid=(n_steps,),
+        grid=(n_seg, bp // bq, n_steps),
         in_specs=[
-            pl.BlockSpec((bp, dp), lambda j: (0, 0)),
-            pl.BlockSpec((lp, dp), lambda j: (0, 0)),
-            pl.BlockSpec((bp, m * cpad), lambda j: (0, 0)),
-            pl.BlockSpec((m, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
+            pl.BlockSpec((bq, dp), lambda z, i, j: (i, 0)),
+            stacked_spec((lp, dp), lambda z, i, j: (z, 0, 0)),
+            pl.BlockSpec((bq, m * cpad), lambda z, i, j: (i, 0)),
+            stacked_spec((m, bn), lambda z, i, j: (z, 0, j)),
+            stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
+            stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
-            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bp, kp), jnp.int32),
-            jax.ShapeDtypeStruct((bp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bp, lp), jnp.float32),
-            pltpu.VMEM((bp, kp), jnp.float32),
-            pltpu.VMEM((bp, kp), jnp.int32),
+            pltpu.VMEM((bq, lp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(qp, cp, lutp, codes_t, clp.reshape(1, np_), gp.reshape(1, np_))
-    return lids[:b, :k], sims[:b, :k]
+    )(qp, cp, lutp, codes_t, clp.reshape(n_seg, 1, np_), gp.reshape(n_seg, 1, np_))
+    return lids[:, :b, :k], sims[:, :b, :k]
 
 
 # ---------------------------------------------------------------------------

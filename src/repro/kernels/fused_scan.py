@@ -20,9 +20,14 @@ HBM. Two implementations share one contract:
 
 Memory-layout contract (shared by every fused kernel in this repo)
 ------------------------------------------------------------------
-* All operands are row-major; the segment axis is tiled by ``bn`` and every
-  other operand (queries, centroids, scale) stays VMEM-resident across the
-  whole grid, so the embedding dim rides along padded to a multiple of 128.
+* All operands are row-major. One kernel call covers a stack of segments:
+  the grid is ``(segment, query block, segment tile)``, the segment axis is
+  tiled by ``bn``, the queries by ``bq`` rows, and the per-segment
+  centroids plus the scale stay VMEM-resident while a segment is scanned.
+  The embedding dim rides along padded to a multiple of 128.
+* Scoring and probe matmuls run at ``Precision.HIGHEST`` in the kernels and
+  in the XLA references alike, so both score in f32 on the TPU (XLA's
+  default there is one bf16 pass) and return the same result sets.
 * Inputs are zero-padded to block multiples; the padding is masked via
   ``cluster_of == -1`` (padded rows belong to no cluster), NEVER by score
   sentinels written into the input arrays.
@@ -45,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import HIGHEST
 
 
 def _round_up(v: int, m: int) -> int:
@@ -75,8 +82,8 @@ def probe_and_init(q_ref, c_ref, cmask_scr, vals_scr, lids_scr, *, nlist: int, n
     """
     csim = jax.lax.dot_general(
         q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (Bp, Lp)
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )  # (bq, Lp)
     col = jax.lax.broadcasted_iota(jnp.int32, csim.shape, 1)
     csim = jnp.where(col < nlist, csim, -jnp.inf)
 
@@ -112,7 +119,7 @@ def merge_tile_topk(
     probed = jax.lax.dot_general(
         cmask_scr[...], onehot, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # (Bp, bn)
+    )  # (bq, bn)
     ok = (probed > 0.5) & (cl >= 0)
     if mask_dead:
         ok = ok & (gid_ref[...] >= 0)
@@ -123,6 +130,7 @@ def merge_tile_topk(
     lids = jnp.concatenate([lids_scr[...], lid_tile], axis=1)
     col = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
     bp, kp = vals_scr.shape
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bp, kp), 1)
 
     def body(t, carry):
         vals, out_v, out_l = carry
@@ -132,8 +140,9 @@ def merge_tile_topk(
         sel = col == idx
         pick = jnp.sum(jnp.where(sel, lids, 0), axis=1, keepdims=True)
         pick = jnp.where(jnp.isfinite(m), pick, -1).astype(jnp.int32)
-        out_v = jax.lax.dynamic_update_slice(out_v, m, (0, t))
-        out_l = jax.lax.dynamic_update_slice(out_l, pick, (0, t))
+        # write slot t through a lane mask: Mosaic has no dynamic_update_slice
+        out_v = jnp.where(slot == t, m, out_v)
+        out_l = jnp.where(slot == t, pick, out_l)
         vals = jnp.where(sel, -jnp.inf, vals)
         return vals, out_v, out_l
 
@@ -154,7 +163,7 @@ def _fused_sq8_kernel(
     q_ref, c_ref, scale_ref, codes_ref, cl_ref, gid_ref, lid_out, sim_out,
     cmask_scr, vals_scr, lids_scr, *, nlist, nprobe, k, n_steps, mask_dead,
 ):
-    j = pl.program_id(0)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -162,8 +171,9 @@ def _fused_sq8_kernel(
 
     deq = codes_ref[...].astype(jnp.float32) * scale_ref[...]  # (bn, Dp) f32
     scores = jax.lax.dot_general(
-        q_ref[...], deq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (Bp, bn)
+        q_ref[...], deq, (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )  # (bq, bn)
     merge_tile_topk(
         scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, k=k, mask_dead=mask_dead
     )
@@ -174,7 +184,26 @@ def _fused_sq8_kernel(
         sim_out[...] = vals_scr[...]
 
 
-@functools.partial(jax.jit, static_argnames=("nprobe", "k", "mask_dead", "bn", "interpret"))
+def stacked_layout(b: int, s: int, nlist: int, k: int, bq: int, bn: int):
+    """Block sizes shared by the fused kernels: ``(bq, bp, bn, np_, lp, kp)``
+    — the query block and padded query rows, the segment tile and padded
+    segment length, the padded cluster count and the padded top-k width."""
+    bq = min(bq, _round_up(b, 8))
+    bn = min(bn, _round_up(s, 128))
+    return (
+        bq, _round_up(b, bq), bn, _round_up(s, bn), _round_up(nlist, 128), _round_up(k, 128)
+    )
+
+
+def stacked_spec(block, index_map):
+    """BlockSpec of one stacked per-segment operand: the segment axis is
+    squeezed out of the block and picked by grid axis 0."""
+    return pl.BlockSpec((None,) + tuple(block), index_map)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("nprobe", "k", "mask_dead", "bq", "bn", "interpret")
+)
 def fused_ivf_sq8_topk_pallas(
     q: jnp.ndarray,
     codes: jnp.ndarray,
@@ -186,25 +215,28 @@ def fused_ivf_sq8_topk_pallas(
     nprobe: int,
     k: int,
     mask_dead: bool = False,
+    bq: int = 128,
     bn: int = 256,
     interpret: bool = False,
 ):
-    """One segment: q (B, d) f32, codes (s, d) int8, scale (d,), centroids
-    (nlist, d), cluster_of (s,) from :func:`members_to_cluster_of`, gids (s,)
-    -> (lids, sims) each (B, k)."""
+    """Stacked segments in one kernel: q (B, d) f32, codes (n_seg, s, d) int8,
+    scale (d,), centroids (n_seg, nlist, d), cluster_of (n_seg, s) from
+    :func:`members_to_cluster_of`, gids (n_seg, s) -> (lids, sims) each
+    (n_seg, B, k).
+
+    Grid ``(segment, query block, segment tile)``: one launch covers every
+    segment, and VMEM holds one ``bq``-row query block, never the batch."""
     b, d = q.shape
-    s = codes.shape[0]
-    nlist = centroids.shape[0]
-    bp, dp, lp = _round_up(b, 8), _round_up(d, 128), _round_up(nlist, 128)
-    bn = min(bn, _round_up(s, 128))
-    np_ = _round_up(s, bn)
-    kp = _round_up(k, 128)
+    n_seg, s, _ = codes.shape
+    nlist = centroids.shape[1]
+    bq, bp, bn, np_, lp, kp = stacked_layout(b, s, nlist, k, bq, bn)
+    dp = _round_up(d, 128)
     qp = jnp.pad(q.astype(jnp.float32), ((0, bp - b), (0, dp - d)))
-    cp = jnp.pad(centroids.astype(jnp.float32), ((0, lp - nlist), (0, dp - d)))
+    cp = jnp.pad(centroids.astype(jnp.float32), ((0, 0), (0, lp - nlist), (0, dp - d)))
     sp = jnp.pad(scale.astype(jnp.float32), (0, dp - d)).reshape(1, dp)
-    codesp = jnp.pad(codes, ((0, np_ - s), (0, dp - d)))
-    clp = jnp.pad(cluster_of.astype(jnp.int32), (0, np_ - s), constant_values=-1)
-    gp = jnp.pad(gids.astype(jnp.int32), (0, np_ - s), constant_values=-1)
+    codesp = jnp.pad(codes, ((0, 0), (0, np_ - s), (0, dp - d)))
+    clp = jnp.pad(cluster_of.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
+    gp = jnp.pad(gids.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
     n_steps = np_ // bn
 
     lids, sims = pl.pallas_call(
@@ -216,31 +248,34 @@ def fused_ivf_sq8_topk_pallas(
             n_steps=n_steps,
             mask_dead=mask_dead,
         ),
-        grid=(n_steps,),
+        grid=(n_seg, bp // bq, n_steps),
         in_specs=[
-            pl.BlockSpec((bp, dp), lambda j: (0, 0)),
-            pl.BlockSpec((lp, dp), lambda j: (0, 0)),
-            pl.BlockSpec((1, dp), lambda j: (0, 0)),
-            pl.BlockSpec((bn, dp), lambda j: (j, 0)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
+            pl.BlockSpec((bq, dp), lambda z, i, j: (i, 0)),
+            stacked_spec((lp, dp), lambda z, i, j: (z, 0, 0)),
+            pl.BlockSpec((1, dp), lambda z, i, j: (0, 0)),
+            stacked_spec((bn, dp), lambda z, i, j: (z, j, 0)),
+            stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
+            stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
-            pl.BlockSpec((bp, kp), lambda j: (0, 0)),
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bp, kp), jnp.int32),
-            jax.ShapeDtypeStruct((bp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bp, lp), jnp.float32),
-            pltpu.VMEM((bp, kp), jnp.float32),
-            pltpu.VMEM((bp, kp), jnp.int32),
+            pltpu.VMEM((bq, lp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(qp, cp, sp, codesp, clp.reshape(1, np_), gp.reshape(1, np_))
-    return lids[:b, :k], sims[:b, :k]
+    )(qp, cp, sp, codesp, clp.reshape(n_seg, 1, np_), gp.reshape(n_seg, 1, np_))
+    return lids[:, :b, :k], sims[:, :b, :k]
 
 
 # ---------------------------------------------------------------------------
@@ -249,7 +284,7 @@ def fused_ivf_sq8_topk_pallas(
 def probe_candidates(q, centroids, members, nprobe: int) -> jnp.ndarray:
     """Probe top-nprobe clusters and flatten their member lists: (B, P) local
     ids, -1 padded — identical to the composed path's candidate stage."""
-    csim = jnp.dot(q, centroids.T, preferred_element_type=jnp.float32)
+    csim = jnp.dot(q, centroids.T, precision=HIGHEST, preferred_element_type=jnp.float32)
     _, probe = jax.lax.top_k(csim, min(nprobe, centroids.shape[0]))
     return members[probe].reshape(q.shape[0], -1)
 
@@ -280,6 +315,6 @@ def fused_ivf_sq8_topk_xla(
     per-element arithmetic (codes·scale dequant, f32 contraction over d)."""
     cand = probe_candidates(q, centroids, members, nprobe)  # (B, P)
     deq = codes.astype(jnp.float32) * scale[None, :]
-    sall = jnp.dot(q, deq.T, preferred_element_type=jnp.float32)  # (B, s)
+    sall = jnp.dot(q, deq.T, precision=HIGHEST, preferred_element_type=jnp.float32)  # (B, s)
     sims = jnp.take_along_axis(sall, jnp.maximum(cand, 0), axis=1)
     return topk_candidates(cand, sims, gids, k=k, mask_dead=mask_dead)

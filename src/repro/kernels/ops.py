@@ -7,8 +7,9 @@
   * "pallas_interpret" — the Pallas kernel executed in interpret mode (CPU
                           correctness validation; used by the test suite).
 
-The global default is "xla" on CPU hosts and "pallas" when a TPU backend is
-present, override per-call or via set_default_impl().
+The default is resolved on first use, not at import: "pallas" when JAX's
+default backend is a TPU, else "xla". Override it per call or with
+set_default_impl().
 """
 from __future__ import annotations
 
@@ -20,21 +21,44 @@ import jax.numpy as jnp
 
 from . import ref
 
-_DEFAULT_IMPL = "pallas" if any(d.platform == "tpu" for d in jax.devices()) else "xla"
+IMPLS = ("xla", "pallas", "pallas_interpret")
+_DEFAULT_IMPL: Optional[str] = None  # resolved on first use
 
 
 def set_default_impl(impl: str) -> None:
+    """Pin the impl that ``impl=None`` resolves to. Jitted programs traced
+    under the previous impl are dropped from JAX's in-memory caches, so the
+    next call of any pipeline retraces with the new one."""
     global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret")
+    if impl not in IMPLS:
+        raise ValueError(f"unknown impl {impl!r}; use one of {IMPLS}")
+    if impl != _DEFAULT_IMPL:
+        jax.clear_caches()
     _DEFAULT_IMPL = impl
 
 
 def get_default_impl() -> str:
+    global _DEFAULT_IMPL
+    if _DEFAULT_IMPL is None:
+        _DEFAULT_IMPL = "pallas" if jax.default_backend() == "tpu" else "xla"
     return _DEFAULT_IMPL
 
 
 def _resolve(impl: Optional[str]) -> str:
-    return impl or _DEFAULT_IMPL
+    impl = impl or get_default_impl()
+    if impl == "pallas_interpret" and jax.default_backend() == "tpu":
+        raise ValueError("impl='pallas_interpret' on a TPU: use 'pallas'")
+    return impl
+
+
+def _cluster_of(members, s: int):
+    """Per-segment inverse of the member lists, (n_seg, s) — the mask-scan
+    kernels' view of cluster membership. A ``lax.map`` and not a ``vmap``:
+    the TPU compiler takes seconds per hundred segments on a batched
+    scatter, and a fraction of a second on the loop."""
+    from .fused_scan import members_to_cluster_of
+
+    return jax.lax.map(lambda m: members_to_cluster_of(m, s), members)
 
 
 # --------------------------------------------------------------------------
@@ -58,16 +82,6 @@ def l2_distance(queries, database, impl: Optional[str] = None):
     return distance_pallas(queries, database, kind="l2", interpret=impl == "pallas_interpret")
 
 
-@partial(jax.jit, static_argnames=("impl",))
-def pq_adc(lut, codes, impl: Optional[str] = None):
-    impl = _resolve(impl)
-    if impl == "xla":
-        return ref.pq_adc(lut, codes)
-    from .pq_adc import pq_adc_pallas
-
-    return pq_adc_pallas(lut, codes, interpret=impl == "pallas_interpret")
-
-
 @partial(jax.jit, static_argnames=("nprobe", "k", "mask_dead", "impl"))
 def fused_ivf_sq8_topk(q, codes, scale, centroids, members, gids, *,
                        nprobe: int, k: int, mask_dead: bool = False,
@@ -84,11 +98,7 @@ def fused_ivf_sq8_topk(q, codes, scale, centroids, members, gids, *,
     merge); default keeps them, mirroring the composed post-top-k masking.
     """
     impl = _resolve(impl)
-    from .fused_scan import (
-        fused_ivf_sq8_topk_pallas,
-        fused_ivf_sq8_topk_xla,
-        members_to_cluster_of,
-    )
+    from .fused_scan import fused_ivf_sq8_topk_pallas, fused_ivf_sq8_topk_xla
 
     if impl == "xla":
         return jax.vmap(
@@ -96,16 +106,10 @@ def fused_ivf_sq8_topk(q, codes, scale, centroids, members, gids, *,
                 q, c, scale, ce, me, g, nprobe=nprobe, k=k, mask_dead=mask_dead
             )
         )(codes, centroids, members, gids)
-    interp = impl == "pallas_interpret"
-    outs = [
-        fused_ivf_sq8_topk_pallas(
-            q, codes[z], scale, centroids[z],
-            members_to_cluster_of(members[z], codes.shape[1]), gids[z],
-            nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=interp,
-        )
-        for z in range(codes.shape[0])
-    ]
-    return jnp.stack([o[0] for o in outs]), jnp.stack([o[1] for o in outs])
+    return fused_ivf_sq8_topk_pallas(
+        q, codes, scale, centroids, _cluster_of(members, codes.shape[1]), gids,
+        nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=impl == "pallas_interpret",
+    )
 
 
 @partial(jax.jit, static_argnames=("nprobe", "k", "mask_dead", "impl"))
@@ -121,7 +125,6 @@ def fused_ivf_pq_topk(q, lut, codes, centroids, members, gids, *,
     """
     impl = _resolve(impl)
     from .fused_adc import fused_ivf_pq_topk_pallas, fused_ivf_pq_topk_xla
-    from .fused_scan import members_to_cluster_of
 
     if impl == "xla":
         return jax.vmap(
@@ -129,16 +132,10 @@ def fused_ivf_pq_topk(q, lut, codes, centroids, members, gids, *,
                 q, lut, c, ce, me, g, nprobe=nprobe, k=k, mask_dead=mask_dead
             )
         )(codes, centroids, members, gids)
-    interp = impl == "pallas_interpret"
-    outs = [
-        fused_ivf_pq_topk_pallas(
-            q, lut, codes[z], centroids[z],
-            members_to_cluster_of(members[z], codes.shape[1]), gids[z],
-            nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=interp,
-        )
-        for z in range(codes.shape[0])
-    ]
-    return jnp.stack([o[0] for o in outs]), jnp.stack([o[1] for o in outs])
+    return fused_ivf_pq_topk_pallas(
+        q, lut, codes, centroids, _cluster_of(members, codes.shape[1]), gids,
+        nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=impl == "pallas_interpret",
+    )
 
 
 @partial(jax.jit, static_argnames=("k", "impl"))

@@ -9,37 +9,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: f32 contractions on every backend: XLA's TPU default is one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def batched_ip(queries: jnp.ndarray, database: jnp.ndarray) -> jnp.ndarray:
     """Inner-product similarity matrix. queries (q, d), database (n, d) -> (q, n)."""
-    return jnp.dot(queries, database.T, preferred_element_type=jnp.float32)
+    return jnp.dot(queries, database.T, precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def l2_distance(queries: jnp.ndarray, database: jnp.ndarray) -> jnp.ndarray:
     """Squared-L2 distance matrix via the ||q||^2 - 2qx + ||x||^2 expansion."""
     qn = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1)
     xn = jnp.sum(database.astype(jnp.float32) ** 2, axis=-1)
-    ip = jnp.dot(queries, database.T, preferred_element_type=jnp.float32)
+    ip = jnp.dot(queries, database.T, precision=HIGHEST, preferred_element_type=jnp.float32)
     return qn[:, None] - 2.0 * ip + xn[None, :]
-
-
-def pq_adc(lut: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
-    """PQ asymmetric distance computation.
-
-    lut:   (q, m, c) per-query lookup tables (distance of query sub-vector to
-           each of the c codewords of each of the m sub-quantizers).
-    codes: (n, m) uint8/int32 database codes.
-    returns (q, n) summed distances:  out[q, n] = sum_m lut[q, m, codes[n, m]].
-    """
-    q, m, c = lut.shape
-    n = codes.shape[0]
-    codes = codes.astype(jnp.int32)
-    gathered = jnp.take_along_axis(
-        lut[:, None, :, :],  # (q, 1, m, c)
-        jnp.broadcast_to(codes[None, :, :, None], (q, n, m, 1)),
-        axis=3,
-    )  # (q, n, m, 1)
-    return jnp.sum(gathered[..., 0], axis=-1)
 
 
 def flash_attention(

@@ -25,9 +25,11 @@ slot)`` index) lands exactly where the unsharded merge puts it.
 
 Dispatch modes: ``shard_map`` (real devices, or host-emulated via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``), ``vmap`` (shard
-axis batched on one device — same math, no parallelism; what the test suite
-uses when the mesh is bigger than the machine), and a direct single-device
-path for ``n_shards=1``. See ``docs/SHARDING.md`` for the full contract.
+axis batched on one device — same math, no parallelism; only when asked for
+by name, as the test suite does when the mesh is bigger than the machine),
+and a direct single-device path for ``n_shards=1``. More shards than devices
+under ``shard_map`` (the ``auto`` choice) raise instead of emulating. See
+``docs/SHARDING.md`` for the full contract.
 """
 from __future__ import annotations
 
@@ -38,7 +40,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributed.sharding import ShardingRules, make_shard_mesh, segment_placement
@@ -183,9 +184,9 @@ def _sharded_chunk(
         def leaf_sm(q_l, arrays_l, alive_l):
             pid, psc = leaf(q_l, arrays_l, alive_l)
             return pid[None], psc[None]  # local leading shard axis of 1
-        parts_i, parts_s = shard_map(
+        parts_i, parts_s = jax.shard_map(
             leaf_sm, mesh=mesh, in_specs=specs_in,
-            out_specs=(P("shard"), P("shard")), check_rep=False,
+            out_specs=(P("shard"), P("shard")), check_vma=False,
         )(q, arrays, alive)
     else:  # "vmap": shard axis batched on one device — same math
         arrays_v = {
@@ -273,16 +274,16 @@ class ShardedVDMS:
         if self.n_shards < 1:
             raise ValueError(f"n_shards must be >= 1, got {self.n_shards}")
         if dispatch == "auto":
-            if self.n_shards == 1:
-                dispatch = "direct"
-            elif mesh is not None or self.n_shards <= len(jax.devices()):
-                dispatch = "shard_map"
-            else:
-                dispatch = "vmap"  # mesh bigger than the machine: emulate
+            dispatch = "direct" if self.n_shards == 1 else "shard_map"
         if dispatch not in ("direct", "shard_map", "vmap"):
             raise ValueError(f"unknown dispatch {dispatch!r}")
         if dispatch == "direct" and self.n_shards != 1:
             raise ValueError("dispatch='direct' requires n_shards=1")
+        if dispatch == "shard_map" and mesh is None and self.n_shards > len(jax.devices()):
+            raise ValueError(
+                f"{self.n_shards} shards but {len(jax.devices())} devices: "
+                "ask for dispatch='vmap' to emulate the mesh on one device"
+            )
         self.dispatch = dispatch
         self.mesh = mesh
         if dispatch == "shard_map" and self.mesh is None:

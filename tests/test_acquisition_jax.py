@@ -17,9 +17,9 @@ Covers the contracts of the fused jitted recommend path:
 """
 import json
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (
     GP,
@@ -88,7 +88,7 @@ def test_hvi_jax_matches_numpy_random_fronts():
         pts = rng.random((64, 2)) * 12 - 2.0  # includes below-ref points
         want = hvi_2d(pts, front, ref)
         fp, fm = _pad_front(front)
-        with enable_x64():
+        with jax.enable_x64(True):
             got = np.asarray(hvi_2d_jax(pts, fp, fm, ref))
         np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-12)
 
@@ -103,7 +103,7 @@ def test_hvi_jax_padding_and_dominated_points_are_inert():
     fp_dom, fm_dom = fp.copy(), fm.copy()
     fp_dom[len(front)] = front.min(axis=0) * 0.5
     fm_dom[len(front)] = True
-    with enable_x64():
+    with jax.enable_x64(True):
         base = np.asarray(hvi_2d_jax(pts, fp, fm, ref))
         dom = np.asarray(hvi_2d_jax(pts, fp_dom, fm_dom, ref))
     np.testing.assert_allclose(dom, base, rtol=1e-12, atol=1e-12)
@@ -114,7 +114,7 @@ def test_hvi_jax_empty_front():
     ref = np.zeros(2)
     fp = np.zeros((4, 2))
     fm = np.zeros((4,), bool)  # fully masked == empty front
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(hvi_2d_jax(pts, fp, fm, ref))
     np.testing.assert_allclose(got, [6.0, 0.0], rtol=1e-12)
 
@@ -128,7 +128,7 @@ def test_ehvi_jax_matches_numpy_with_shared_draws():
     eps = np.random.default_rng(3).standard_normal((64, 40, 2))
     want = ehvi_mc(mean, std, front, ref, _FixedEps(eps), n_samples=64)
     fp, fm = _pad_front(front)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ehvi_mc_jax(mean, std, fp, fm, ref, eps))
     np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-12)
 
@@ -151,7 +151,7 @@ def test_ei_cei_jax_match_numpy(best):
     std = np.abs(rng.normal(0.0, 1.0, size=50)) + 1e-13
     mean_r = rng.random(50)
     std_r = rng.random(50) * 0.1 + 1e-13
-    with enable_x64():
+    with jax.enable_x64(True):
         got_ei = np.asarray(ei_jax(mean, std, 1.0))
         got_cei = np.asarray(cei_jax(mean, std, mean_r, std_r, best, 0.9))
     np.testing.assert_allclose(got_ei, ei(mean, std, 1.0), rtol=1e-9, atol=1e-15)

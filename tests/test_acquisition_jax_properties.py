@@ -2,12 +2,12 @@
 the JAX EHVI/CEI/HVI ports match the numpy references across random fronts,
 refs and degenerate cases, and the rank-1 Cholesky update in
 ``GP.condition_on`` matches a full refactorization."""
+import jax
 import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="optional test dep; pip install -e .[test]")
 from hypothesis import given, settings, strategies as st
-from jax.experimental import enable_x64
 
 from repro.core import GP, cei, cei_jax, ehvi_mc, ehvi_mc_jax, hvi_2d, hvi_2d_jax, pareto_front
 from repro.core.gp import _posterior_padded
@@ -35,7 +35,7 @@ def test_hvi_jax_matches_numpy(front_pts, pts, r0, r1, extra):
     front = pareto_front(front_pts)
     want = hvi_2d(pts, front, ref)
     fp, fm = _pad_front(front, extra)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(hvi_2d_jax(pts, fp, fm, ref))
     np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-9)
 
@@ -61,7 +61,7 @@ def test_ehvi_jax_matches_numpy(front_pts, seed, extra):
     eps = rng.standard_normal((16, c, 2))
     want = ehvi_mc(mean, std, front, ref, _FixedEps(eps), n_samples=16)
     fp, fm = _pad_front(front, extra)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ehvi_mc_jax(mean, std, fp, fm, ref, eps))
     np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-9)
 
@@ -79,7 +79,7 @@ def test_cei_jax_matches_numpy(seed, best, rlim):
     mean_r = rng.random(20) * 1.5
     std_r = rng.random(20) * 0.2 + 1e-12
     want = cei(mean, std, mean_r, std_r, best, rlim)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(cei_jax(mean, std, mean_r, std_r, best, rlim))
     np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-12)
 

@@ -84,7 +84,9 @@ MINI_DRYRUN = textwrap.dedent(
     from repro.configs.base import get_arch, reduce, SHAPES
     from repro.distributed.sharding import ShardingRules
     from repro.launch.dryrun import _compile_step
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     cfg = dataclasses.replace(
         reduce(get_arch("glm4-9b")), name="mini", d_model=256, n_heads=8,
         n_kv_heads=4, head_dim=32, d_ff=512, vocab=1024, n_layers=2,
@@ -108,6 +110,7 @@ MINI_DRYRUN = textwrap.dedent(
 def test_mini_dryrun_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # the child never takes a chip from its parent
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", MINI_DRYRUN],

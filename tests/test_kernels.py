@@ -9,7 +9,6 @@ from repro.kernels import ref
 from repro.kernels.distance import distance_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.flash_xla import flash_attention_xla
-from repro.kernels.pq_adc import pq_adc_pallas
 
 RNG = np.random.default_rng(0)
 
@@ -25,15 +24,6 @@ def test_distance_kernel_matches_ref(q, n, d, kind, dtype):
     want = ref.batched_ip(Q, X) if kind == "ip" else ref.l2_distance(Q, X)
     tol = 2e-4 if dtype == jnp.float32 else 0.15
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize("q,n,m,c", [(16, 300, 8, 256), (7, 1000, 12, 64), (128, 512, 4, 16), (3, 33, 2, 16)])
-def test_pq_adc_kernel_matches_ref(q, n, m, c):
-    lut = jnp.asarray(RNG.standard_normal((q, m, c)), jnp.float32)
-    codes = jnp.asarray(RNG.integers(0, c, (n, m)), jnp.int32)
-    got = pq_adc_pallas(lut, codes, interpret=True)
-    want = ref.pq_adc(lut, codes)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
 FA_CASES = [

@@ -32,15 +32,6 @@ def test_l2_distance_parity(q, n, d):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("q,n,m,c", [(8, 200, 8, 64), (4, 64, 4, 16)])
-def test_pq_adc_parity(q, n, m, c):
-    lut = jnp.asarray(RNG.standard_normal((q, m, c)), jnp.float32)
-    codes = jnp.asarray(RNG.integers(0, c, (n, m)), jnp.int32)
-    got = ops.pq_adc(lut, codes, impl="pallas_interpret")
-    want = ops.pq_adc(lut, codes, impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
-
-
 @pytest.mark.parametrize(
     "b,sq,sk,hq,hkv,dh,causal,win",
     [(1, 64, 64, 4, 2, 32, True, None), (1, 96, 96, 2, 1, 32, True, 48)],
@@ -168,3 +159,48 @@ def test_fused_pq_topk_parity(s, nlist, nprobe, k, dead, mask_dead):
         ops.fused_ivf_pq_topk(*args, impl="pallas_interpret", **kw),
         ops.fused_ivf_pq_topk(*args, impl="xla", **kw),
     )
+
+
+def _sq8_case(n_seg, b, s, nlist, nprobe):
+    d = 40
+    segs, cents, members, gids = _ivf_fixture(n_seg, s, d, nlist, nprobe)
+    scale = (np.abs(segs).max(axis=(0, 1)) / 127.0 + 1e-12).astype(np.float32)
+    codes = np.clip(np.round(segs / scale), -127, 127).astype(np.int8)
+    q = np.random.default_rng(6).standard_normal((b, d)).astype(np.float32)
+    return tuple(map(jnp.asarray, (q, codes, scale, cents, members, gids)))
+
+
+def _pq_case(n_seg, b, s, nlist, nprobe):
+    d, m, c = 40, 4, 64  # 64**4 code words: exact score ties are rare
+    segs, cents, members, gids = _ivf_fixture(n_seg, s, d, nlist, nprobe)
+    rng = np.random.default_rng(7)
+    codes = rng.integers(0, c, (n_seg, s, m)).astype(np.uint8)
+    q = rng.standard_normal((b, d)).astype(np.float32)
+    lut = rng.standard_normal((b, m, c)).astype(np.float32)
+    return tuple(map(jnp.asarray, (q, lut, codes, cents, members, gids)))
+
+
+@pytest.mark.parametrize("family", ["sq8", "pq"])
+@pytest.mark.parametrize("b,bq", [(20, 8), (33, 16), (130, 128)])
+def test_fused_kernels_tile_queries_and_segments(family, b, bq):
+    """The stacked kernels' grid (segment, query block, tile): several
+    segments and query blocks, ragged last blocks, must equal the XLA
+    reference per segment."""
+    from repro.kernels.fused_adc import fused_ivf_pq_topk_pallas
+    from repro.kernels.fused_scan import fused_ivf_sq8_topk_pallas
+
+    kw = dict(nprobe=2, k=12, mask_dead=False)
+    if family == "sq8":
+        args = _sq8_case(3, b, 300, 6, 2)
+        got = fused_ivf_sq8_topk_pallas(
+            *args[:4], ops._cluster_of(args[4], 300), args[5], bq=bq, interpret=True, **kw
+        )
+        want = ops.fused_ivf_sq8_topk(*args, impl="xla", **kw)
+    else:
+        args = _pq_case(3, b, 300, 6, 2)
+        got = fused_ivf_pq_topk_pallas(
+            *args[:4], ops._cluster_of(args[4], 300), args[5], bq=bq, interpret=True, **kw
+        )
+        want = ops.fused_ivf_pq_topk(*args, impl="xla", **kw)
+    assert np.asarray(got[0]).shape == (3, b, 12)
+    _assert_topk_sets_match(got, want)

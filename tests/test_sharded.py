@@ -283,6 +283,14 @@ def test_nothing_sealed_raises():
         ShardedVDMS.from_live(live, n_shards=2)
 
 
+def test_more_shards_than_devices_raises_unless_vmap_by_name():
+    inst = _instance(_dataset())
+    n = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match="dispatch='vmap'"):
+        ShardedVDMS.from_instance(inst, n_shards=n)
+    assert ShardedVDMS.from_instance(inst, n_shards=n, dispatch="vmap").dispatch == "vmap"
+
+
 def test_analytic_model_reduces_to_engine_at_one_shard():
     ds = _dataset()
     inst = _instance(ds)
