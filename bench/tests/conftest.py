@@ -1,0 +1,11 @@
+"""Harness checks, run with ``python -m pytest bench/tests`` from the root.
+
+They run on the CPU at a tiny size; nothing here measures speed.
+"""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for path in (ROOT, ROOT / "src"):
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
