@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 
 import numpy as np
 
+from ..obs import span
 from .objectives import EvalBackend, TuningFailure
 from .space import Config
 from .tuner import Observation, TunerBase
@@ -211,6 +212,17 @@ class _TimeoutBackend:
 # ---------------------------------------------------------------------------
 # Evaluation executors
 # ---------------------------------------------------------------------------
+def _evaluate(backend: EvalBackend, cfg: Config) -> Tuple[Any, float]:
+    """One evaluation under the ``tuner.evaluate`` span: (result or the
+    :class:`TuningFailure` it raised, its seconds)."""
+    with span("tuner.evaluate") as took:
+        try:
+            result: Any = backend(cfg)
+        except TuningFailure as e:
+            result = e
+    return result, took.seconds
+
+
 class SequentialExecutor:
     """Evaluate one config at a time through ``backend(cfg)`` — results are
     yielded as they land, so observations are told (and checkpointable)
@@ -220,12 +232,7 @@ class SequentialExecutor:
 
     def execute(self, backend: EvalBackend, cfgs: Sequence[Config]) -> Iterator[Tuple[Any, float]]:
         for cfg in cfgs:
-            t0 = time.perf_counter()
-            try:
-                result: Any = backend(cfg)
-            except TuningFailure as e:
-                result = e
-            yield result, time.perf_counter() - t0
+            yield _evaluate(backend, cfg)
 
 
 class BatchExecutor:
@@ -243,9 +250,9 @@ class BatchExecutor:
         if eb is None or len(cfgs) == 1:
             yield from SequentialExecutor().execute(backend, cfgs)
             return
-        t0 = time.perf_counter()
-        results = eb(list(cfgs))
-        per_cfg = (time.perf_counter() - t0) / max(len(cfgs), 1)
+        with span("tuner.evaluate") as took:
+            results = eb(list(cfgs))
+        per_cfg = took.seconds / max(len(cfgs), 1)
         for result in results:
             yield result, per_cfg
 
@@ -261,20 +268,12 @@ class ThreadedExecutor:
         self.max_workers = max_workers
 
     def execute(self, backend: EvalBackend, cfgs: Sequence[Config]) -> Iterator[Tuple[Any, float]]:
-        def one(cfg: Config) -> Tuple[Any, float]:
-            t0 = time.perf_counter()
-            try:
-                result: Any = backend(cfg)
-            except TuningFailure as e:
-                result = e
-            return result, time.perf_counter() - t0
-
         workers = self.max_workers or min(max(len(cfgs), 1), os.cpu_count() or 4)
         if len(cfgs) <= 1 or workers == 1:
-            yield from (one(c) for c in cfgs)
+            yield from (_evaluate(backend, c) for c in cfgs)
             return
         with ThreadPoolExecutor(max_workers=workers) as ex:
-            yield from ex.map(one, cfgs)
+            yield from ex.map(lambda cfg: _evaluate(backend, cfg), cfgs)
 
 
 _EXECUTORS = {
@@ -401,9 +400,9 @@ class TuningSession:
                     break
                 if stop is not None and stop(self):
                     break
-                t0 = time.perf_counter()
-                cfgs = list(self.tuner.ask(n_iters - self.n_observations))
-                ask_s = time.perf_counter() - t0
+                with span("tuner.recommend") as took:
+                    cfgs = list(self.tuner.ask(n_iters - self.n_observations))
+                ask_s = took.seconds
                 if not cfgs:
                     break  # recommender exhausted (e.g. DefaultOnly)
                 self._pending = cfgs
@@ -605,9 +604,9 @@ class TuningSession:
         start = len(self.tuner.history)
         try:
             if not self._pending:
-                t0 = time.perf_counter()
-                cfgs = list(self.tuner.ask(max(int(n), 1)))
-                ask_s = time.perf_counter() - t0
+                with span("tuner.recommend") as took:
+                    cfgs = list(self.tuner.ask(max(int(n), 1)))
+                ask_s = took.seconds
                 if not cfgs:
                     return []
                 self._pending = cfgs

@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import ref
 
 IMPLS = ("xla", "pallas", "pallas_interpret")
@@ -58,7 +59,8 @@ def _cluster_of(members, s: int):
     scatter, and a fraction of a second on the loop."""
     from .fused_scan import members_to_cluster_of
 
-    return jax.lax.map(lambda m: members_to_cluster_of(m, s), members)
+    with obs.scope("cluster_of"):
+        return jax.lax.map(lambda m: members_to_cluster_of(m, s), members)
 
 
 # --------------------------------------------------------------------------

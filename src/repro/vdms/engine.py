@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .datasets import VectorDataset, recall_at_k
 from .faults import HEALTH_CODE, BuildCrashFault, FaultInjector, TransientEngineFault
 from .indexes import (
@@ -157,6 +158,9 @@ def _pipeline_impl(
 _pipeline = partial(
     jax.jit, static_argnames=("kind", "statics", "k_seg", "topk", "fused", "clamp")
 )(_pipeline_impl)
+#: the jitted program itself, for ``VDMSInstance.search_program``: a caller
+#: may wrap the module's ``_pipeline`` in a host span of its own
+_pipeline_jit = _pipeline
 
 
 @partial(jax.jit, static_argnames=("kind", "statics", "k_seg", "topk"))
@@ -181,25 +185,31 @@ class VDMSInstance:
         self.dataset = dataset
         self.config = dict(config)
         t0 = time.perf_counter()
-        self.plan = plan_segments(
-            dataset.n,
-            int(config["segment_max_size"]),
-            float(config["seal_proportion"]),
-            float(config["graceful_time"]),
-        )
-        segs, gids = stack_sealed(dataset.data, self.plan)
-        key = jax.random.PRNGKey(seed)
-        sys = {
-            "kmeans_iters": int(config["kmeans_iters"]),
-            "storage_bf16": bool(config["storage_bf16"]),
-        }
-        self.bundle = build_index(key, segs, gids, config["index_type"], config, sys)
-        g0 = self.plan.growing_start
-        g_searched = self.plan.growing_searched
-        self.growing = jnp.asarray(dataset.data[g0 : g0 + g_searched])
-        self.growing_gids = jnp.asarray(np.arange(g0, g0 + g_searched, dtype=np.int32))
-        jax.block_until_ready(list(self.bundle.arrays.values()))
+        with obs.collect() as stages:
+            self.plan = plan_segments(
+                dataset.n,
+                int(config["segment_max_size"]),
+                float(config["seal_proportion"]),
+                float(config["graceful_time"]),
+            )
+            segs, gids = stack_sealed(dataset.data, self.plan)
+            key = jax.random.PRNGKey(seed)
+            sys = {
+                "kmeans_iters": int(config["kmeans_iters"]),
+                "storage_bf16": bool(config["storage_bf16"]),
+            }
+            self.bundle = build_index(key, segs, gids, config["index_type"], config, sys)
+            g0 = self.plan.growing_start
+            g_searched = self.plan.growing_searched
+            with obs.span("build.upload"):
+                self.growing = jnp.asarray(dataset.data[g0 : g0 + g_searched])
+                self.growing_gids = jnp.asarray(np.arange(g0, g0 + g_searched, dtype=np.int32))
+                jax.block_until_ready(list(self.bundle.arrays.values()))
         self.build_time = time.perf_counter() - t0
+        #: seconds of the build by stage (``build.stack_sealed``, ``build.upload``,
+        #: ``build.kmeans``, ...; those of the index family); they sum to at most
+        #: ``build_time``
+        self.build_seconds = stages
         self.k_seg = int(config["topk_merge_width"])
         self.batch = int(config["search_batch_size"])
         # the fused top-k clamp is exact only when every sealed slot is real:
@@ -219,10 +229,10 @@ class VDMSInstance:
             queries = np.concatenate([queries, queries[:pad]], axis=0)
         return jnp.asarray(queries.reshape(n_chunks, b, d))
 
-    def search(self, queries: np.ndarray, topk: int) -> np.ndarray:
-        qc = self._chunked_queries(queries)
-        out = _pipeline(
-            qc,
+    def _pipeline_args(self, queries: np.ndarray, topk: int) -> tuple:
+        """The arguments of the ``_pipeline`` call that searches ``queries``."""
+        return (
+            self._chunked_queries(queries),
             self.bundle.arrays,
             self.growing,
             self.growing_gids,
@@ -233,8 +243,23 @@ class VDMSInstance:
             get_search_pipeline() == "fused",
             self._clamp_ok,
         )
-        out = np.asarray(out).reshape(-1, topk)[: queries.shape[0]]
-        return out
+
+    def search(self, queries: np.ndarray, topk: int) -> np.ndarray:
+        with obs.span("search.prep"):
+            args = self._pipeline_args(queries, topk)
+        with obs.span("search.dispatch"):
+            out = _pipeline(*args)
+        with obs.span("search.fetch"):
+            out = np.asarray(out)
+        return out.reshape(-1, topk)[: queries.shape[0]]
+
+    def search_program(self, n_queries: int, topk: int) -> jax.stages.Compiled:
+        """The compiled program that ``search`` runs for ``n_queries`` queries
+        and ``topk``, for its HLO (``as_text``, whose metadata carries the
+        ``vdms.*`` scopes), ``cost_analysis`` and ``memory_analysis``. Where
+        the persistent compilation cache holds it, it is loaded, not compiled."""
+        queries = np.zeros((n_queries, self.dataset.dim), np.float32)
+        return _pipeline_jit.lower(*self._pipeline_args(queries, topk)).compile()
 
     def memory_gib(self) -> float:
         b = self.bundle.memory_bytes() + self.growing.size * self.growing.dtype.itemsize
@@ -270,19 +295,7 @@ class VDMSInstance:
             elapsed = self._analytic_seconds_per_chunk() * n_chunks
         else:
             times = []
-            qc = self._chunked_queries(queries)
-            args = (
-                qc,
-                self.bundle.arrays,
-                self.growing,
-                self.growing_gids,
-                self.bundle.kind,
-                tuple(sorted(self.bundle.static.items())),
-                self.k_seg,
-                topk,
-                get_search_pipeline() == "fused",
-                self._clamp_ok,
-            )
+            args = self._pipeline_args(queries, topk)
             for _ in range(repeats):
                 t0 = time.perf_counter()
                 jax.block_until_ready(_pipeline(*args))

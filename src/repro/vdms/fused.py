@@ -40,23 +40,48 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import ops
 from .merge import merge_topk
 
 
-def _map_gids(gids, lids):
+def _map_gids(gids, lids, sims):
     """Map per-segment local ids (n_seg, B, k) to global ids via each
-    segment's gid row; empty slots (lid < 0) map to -1."""
-    ids = jax.vmap(lambda g, l: g[jnp.maximum(l, 0)])(gids, lids)
-    return jnp.where(lids >= 0, ids, -1)
+    segment's gid row, with dead-slot masking: empty slots (lid < 0) and
+    padded ones (gid < 0) keep their width but turn -1/-inf, mirroring the
+    composed post-top-k mask. Returns (ids, sims)."""
+    with obs.scope("gid_map"):
+        ids = jax.vmap(lambda g, l: g[jnp.maximum(l, 0)])(gids, lids)
+        ids = jnp.where(lids >= 0, ids, -1)
+        return ids, jnp.where(ids >= 0, sims, -jnp.inf)
+
+
+def _rerank_topk(q, data, lids, k):
+    """IVF_PQR's second stage: score the PQ candidates (n_seg, B, r) exactly
+    against the raw vectors, keep each segment's best ``k`` (padded -1/-inf
+    where ``r < k``). Returns (lids, sims), each (n_seg, B, k)."""
+
+    def rerank(data_z, lids_z):
+        vecs = data_z[jnp.maximum(lids_z, 0)].astype(jnp.float32)  # (B, r, d)
+        exact = jnp.einsum("brd,bd->br", vecs, q)
+        return jnp.where(lids_z >= 0, exact, -jnp.inf)
+
+    exact = jax.vmap(rerank)(data, lids)  # (n_seg, B, r)
+    kk = min(k, exact.shape[-1])
+    with obs.scope("segment_topk"):
+        top_s, top_i = jax.lax.top_k(exact, kk)
+    lids = jnp.take_along_axis(lids, top_i, axis=2)
+    if kk < k:
+        pad = ((0, 0), (0, 0), (0, k - kk))
+        lids = jnp.pad(lids, pad, constant_values=-1)
+        top_s = jnp.pad(top_s, pad, constant_values=-jnp.inf)
+    return lids, top_s
 
 
 def _finish(lids, sims, gids, q, growing, growing_gids, alive, topk):
-    """Shared epilogue: local→global ids, dead-slot masking (gid < 0 slots
-    keep their width but turn -1/-inf, mirroring the composed post-top-k
-    mask), then the shared static/live merge (``repro.vdms.merge``)."""
-    ids = _map_gids(gids, lids)
-    sims = jnp.where(ids >= 0, sims, -jnp.inf)
+    """Shared epilogue: local→global ids with dead-slot masking, then the
+    shared static/live merge (``repro.vdms.merge``)."""
+    ids, sims = _map_gids(gids, lids, sims)
     return merge_topk(ids, sims, q, growing, growing_gids, topk, alive=alive)
 
 
@@ -144,21 +169,8 @@ def fused_search_ivf_pqr(
         k=reorder_k,
         mask_dead=False,
     )  # (n_seg, B, r): the PQ stage only ranks; its scores are discarded
-
-    def rerank(data_z, lids_z):
-        vecs = data_z[jnp.maximum(lids_z, 0)].astype(jnp.float32)  # (B, r, d)
-        exact = jnp.einsum("brd,bd->br", vecs, q)
-        return jnp.where(lids_z >= 0, exact, -jnp.inf)
-
-    exact = jax.vmap(rerank)(arrays["data"], lids)  # (n_seg, B, r)
-    kk = min(k_eff, exact.shape[-1])
-    top_s, top_i = jax.lax.top_k(exact, kk)
-    lids2 = jnp.take_along_axis(lids, top_i, axis=2)
-    if kk < k_eff:
-        pad = ((0, 0), (0, 0), (0, k_eff - kk))
-        lids2 = jnp.pad(lids2, pad, constant_values=-1)
-        top_s = jnp.pad(top_s, pad, constant_values=-jnp.inf)
-    return _finish(lids2, top_s, arrays["gids"], q, growing, growing_gids, alive, topk)
+    lids, sims = _rerank_topk(q, arrays["data"], lids, k_eff)
+    return _finish(lids, sims, arrays["gids"], q, growing, growing_gids, alive, topk)
 
 
 fused_search_ivf_pqr.stages = "probe → PQ ADC scan → exact re-rank → top-k"
@@ -181,8 +193,7 @@ def shard_search_ivf_sq8(q, arrays, *, k_seg, nprobe):
         k=k_seg,
         mask_dead=False,
     )
-    ids = _map_gids(arrays["gids"], lids)
-    return ids, jnp.where(ids >= 0, sims, -jnp.inf)
+    return _map_gids(arrays["gids"], lids, sims)
 
 
 shard_search_ivf_sq8.stages = "probe → int8 dequant scan → shard top-k"
@@ -203,8 +214,7 @@ def shard_search_ivf_pq(q, arrays, *, k_seg, nprobe, m, c):
         k=k_seg,
         mask_dead=False,
     )
-    ids = _map_gids(arrays["gids"], lids)
-    return ids, jnp.where(ids >= 0, sims, -jnp.inf)
+    return _map_gids(arrays["gids"], lids, sims)
 
 
 shard_search_ivf_pq.stages = "probe → PQ ADC scan → shard top-k"
@@ -227,22 +237,8 @@ def shard_search_ivf_pqr(q, arrays, *, k_seg, nprobe, m, c, reorder_k):
         k=reorder_k,
         mask_dead=False,
     )
-
-    def rerank(data_z, lids_z):
-        vecs = data_z[jnp.maximum(lids_z, 0)].astype(jnp.float32)  # (B, r, d)
-        exact = jnp.einsum("brd,bd->br", vecs, q)
-        return jnp.where(lids_z >= 0, exact, -jnp.inf)
-
-    exact = jax.vmap(rerank)(arrays["data"], lids)  # (n_seg, B, r)
-    kk = min(k_seg, exact.shape[-1])
-    top_s, top_i = jax.lax.top_k(exact, kk)
-    lids2 = jnp.take_along_axis(lids, top_i, axis=2)
-    if kk < k_seg:
-        pad = ((0, 0), (0, 0), (0, k_seg - kk))
-        lids2 = jnp.pad(lids2, pad, constant_values=-1)
-        top_s = jnp.pad(top_s, pad, constant_values=-jnp.inf)
-    ids = _map_gids(arrays["gids"], lids2)
-    return ids, jnp.where(ids >= 0, top_s, -jnp.inf)
+    lids, sims = _rerank_topk(q, arrays["data"], lids, k_seg)
+    return _map_gids(arrays["gids"], lids, sims)
 
 
 shard_search_ivf_pqr.stages = "probe → PQ ADC scan → exact re-rank → shard top-k"

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.space import Param
 from ..kernels import ops
 from .fused import (
@@ -95,24 +96,44 @@ def _mask_pad(sims: jnp.ndarray, gids: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(gids >= 0, sims, -jnp.inf)
 
 
+def _segment_topk(sims, cand, gids, k_seg: int):
+    """One segment's best ``k_seg`` of its scored candidates (B, P): global
+    ids and sims, each (B, k_seg). Empty candidates (-1) and padded slots
+    (gid -1) turn -1/-inf but keep their width; a list shorter than
+    ``k_seg`` pads with -1/-inf."""
+    with obs.scope("segment_topk"):
+        sims = jnp.where(cand >= 0, sims, -jnp.inf)
+        k = min(k_seg, sims.shape[1])
+        top_s, top_i = jax.lax.top_k(sims, k)
+    with obs.scope("gid_map"):
+        lids = jnp.take_along_axis(cand, top_i, axis=1)
+        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
+        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
+    if k < k_seg:
+        pad = k_seg - k
+        ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
+        top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    return ids, top_s
+
+
 # =========================================================================
 # FLAT — exhaustive
 # =========================================================================
 def build_flat(key, segs: np.ndarray, gids: np.ndarray, params, sys, frozen=None) -> IndexBundle:
-    return IndexBundle(
-        kind="FLAT",
-        arrays={"data": _storage(segs, sys["storage_bf16"]), "gids": jnp.asarray(gids)},
-        static={},
-    )
+    with obs.span("build.upload"):
+        arrays = {"data": _storage(segs, sys["storage_bf16"]), "gids": jnp.asarray(gids)}
+    return IndexBundle(kind="FLAT", arrays=arrays, static={})
 
 
 def _search_flat(q: jnp.ndarray, arrays, *, k_seg: int):
     def per_seg(seg):
         data, gids = seg
         sims = ops.batched_ip(q, data)  # (B, S)
-        sims = _mask_pad(sims, gids[None, :])
-        top_s, top_i = jax.lax.top_k(sims, k_seg)
-        return gids[top_i], top_s
+        with obs.scope("segment_topk"):
+            sims = _mask_pad(sims, gids[None, :])
+            top_s, top_i = jax.lax.top_k(sims, k_seg)
+        with obs.scope("gid_map"):
+            return gids[top_i], top_s
 
     ids, sims = jax.lax.map(per_seg, (arrays["data"], arrays["gids"]))
     return ids, sims  # (n_seg, B, k_seg)
@@ -121,39 +142,54 @@ def _search_flat(q: jnp.ndarray, arrays, *, k_seg: int):
 # =========================================================================
 # IVF family
 # =========================================================================
-def _build_ivf_common(key, segs, gids, nlist, kmeans_iters):
+def _build_ivf_common(key, segs, params, kmeans_iters):
+    """Every IVF family's clustering: per-segment spherical k-means and the
+    member lists. Returns (nprobe, centroids (n_seg, nlist, d), members
+    (n_seg, nlist, cap)) on the host."""
     n_seg, s, d = segs.shape
-    nlist = int(min(max(nlist, 4), max(s // 8, 4)))
+    nlist = int(min(max(params["nlist"], 4), max(s // 8, 4)))
+    nprobe = int(min(params["nprobe"], nlist))
     keys = jax.random.split(key, n_seg)
-    cents, assigns = jax.vmap(lambda k, x: kmeans(k, x, nlist, kmeans_iters))(
-        keys, jnp.asarray(segs)
-    )
-    return nlist, np.asarray(cents), np.asarray(assigns)
+    with obs.span("build.upload"):
+        x = jax.block_until_ready(jnp.asarray(segs))
+    with obs.span("build.kmeans"):
+        cents, assigns = jax.vmap(lambda k, xs: kmeans(k, xs, nlist, kmeans_iters))(keys, x)
+        cents, assigns = np.asarray(cents), np.asarray(assigns)
+    with obs.span("build.member_lists"):
+        cap = _ivf_cap(s, nlist, nprobe)
+        members = np.stack([_member_lists(assigns[z], nlist, cap) for z in range(n_seg)])
+    return nprobe, cents, members
+
+
+def _sq8_encode(segs, frozen):
+    """(scale (d,) float32, int8 codes): one scale per dimension shared by
+    every segment, or the frozen one of an earlier build."""
+    with obs.span("build.encode"):
+        if frozen is None:
+            scale = np.abs(segs).max(axis=(0, 1)) / 127.0 + 1e-12
+        else:
+            scale = np.asarray(frozen["scale"], np.float32)
+        codes = np.clip(np.round(segs / scale), -127, 127).astype(np.int8)
+    return scale.astype(np.float32), codes
 
 
 def build_ivf_flat(key, segs, gids, params, sys, frozen=None) -> IndexBundle:
-    nlist, cents, assigns = _build_ivf_common(
-        key, segs, gids, params["nlist"], sys["kmeans_iters"]
-    )
-    nprobe = int(min(params["nprobe"], nlist))
-    cap = _ivf_cap(segs.shape[1], nlist, nprobe)
-    members = np.stack([_member_lists(assigns[z], nlist, cap) for z in range(len(segs))])
-    return IndexBundle(
-        kind="IVF_FLAT",
-        arrays={
+    nprobe, cents, members = _build_ivf_common(key, segs, params, sys["kmeans_iters"])
+    with obs.span("build.upload"):
+        arrays = {
             "data": _storage(segs, sys["storage_bf16"]),
             "gids": jnp.asarray(gids),
             "centroids": jnp.asarray(cents),
             "members": jnp.asarray(members),
-        },
-        static={"nprobe": nprobe},
-    )
+        }
+    return IndexBundle(kind="IVF_FLAT", arrays=arrays, static={"nprobe": nprobe})
 
 
 def _gather_candidates(q, centroids, members, *, nprobe):
     """Probe top-nprobe clusters; return flattened candidate local ids (B, P)."""
-    csim = jnp.dot(q, centroids.T, preferred_element_type=jnp.float32)  # (B, nlist)
-    _, probe = jax.lax.top_k(csim, nprobe)  # (B, nprobe)
+    with obs.scope("probe"):
+        csim = jnp.dot(q, centroids.T, preferred_element_type=jnp.float32)  # (B, nlist)
+        _, probe = jax.lax.top_k(csim, nprobe)  # (B, nprobe)
     cand = members[probe]  # (B, nprobe, cap)
     return cand.reshape(q.shape[0], -1)  # (B, P)
 
@@ -165,17 +201,7 @@ def _search_ivf_flat(q, arrays, *, k_seg: int, nprobe: int):
         safe = jnp.maximum(cand, 0)
         vecs = data[safe]  # (B, P, d)
         sims = jnp.einsum("bpd,bd->bp", vecs.astype(jnp.float32), q)
-        sims = jnp.where(cand >= 0, sims, -jnp.inf)
-        k = min(k_seg, sims.shape[1])
-        top_s, top_i = jax.lax.top_k(sims, k)
-        lids = jnp.take_along_axis(cand, top_i, axis=1)
-        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
-        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
-        if k < k_seg:  # pad to fixed k_seg
-            pad = k_seg - k
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-        return ids, top_s
+        return _segment_topk(sims, cand, gids, k_seg)
 
     return jax.lax.map(
         per_seg,
@@ -184,28 +210,17 @@ def _search_ivf_flat(q, arrays, *, k_seg: int, nprobe: int):
 
 
 def build_ivf_sq8(key, segs, gids, params, sys, frozen=None) -> IndexBundle:
-    nlist, cents, assigns = _build_ivf_common(
-        key, segs, gids, params["nlist"], sys["kmeans_iters"]
-    )
-    nprobe = int(min(params["nprobe"], nlist))
-    cap = _ivf_cap(segs.shape[1], nlist, nprobe)
-    members = np.stack([_member_lists(assigns[z], nlist, cap) for z in range(len(segs))])
-    if frozen is None:
-        scale = np.abs(segs).max(axis=(0, 1)) / 127.0 + 1e-12  # (d,) shared scale
-    else:
-        scale = np.asarray(frozen["scale"], np.float32)
-    codes = np.clip(np.round(segs / scale), -127, 127).astype(np.int8)
-    return IndexBundle(
-        kind="IVF_SQ8",
-        arrays={
+    nprobe, cents, members = _build_ivf_common(key, segs, params, sys["kmeans_iters"])
+    scale, codes = _sq8_encode(segs, frozen)
+    with obs.span("build.upload"):
+        arrays = {
             "codes": jnp.asarray(codes),
-            "scale": jnp.asarray(scale.astype(np.float32)),
+            "scale": jnp.asarray(scale),
             "gids": jnp.asarray(gids),
             "centroids": jnp.asarray(cents),
             "members": jnp.asarray(members),
-        },
-        static={"nprobe": nprobe},
-    )
+        }
+    return IndexBundle(kind="IVF_SQ8", arrays=arrays, static={"nprobe": nprobe})
 
 
 def _search_ivf_sq8(q, arrays, *, k_seg: int, nprobe: int):
@@ -217,17 +232,7 @@ def _search_ivf_sq8(q, arrays, *, k_seg: int, nprobe: int):
         safe = jnp.maximum(cand, 0)
         vecs = codes[safe].astype(jnp.float32) * scale[None, None, :]
         sims = jnp.einsum("bpd,bd->bp", vecs, q)
-        sims = jnp.where(cand >= 0, sims, -jnp.inf)
-        k = min(k_seg, sims.shape[1])
-        top_s, top_i = jax.lax.top_k(sims, k)
-        lids = jnp.take_along_axis(cand, top_i, axis=1)
-        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
-        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
-        if k < k_seg:
-            pad = k_seg - k
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-        return ids, top_s
+        return _segment_topk(sims, cand, gids, k_seg)
 
     return jax.lax.map(
         per_seg,
@@ -242,45 +247,40 @@ def build_ivf_pq(key, segs, gids, params, sys, frozen=None) -> IndexBundle:
         m -= 1
     nbits = int(params["nbits"])
     c = 2**nbits
-    nlist, cents, assigns = _build_ivf_common(
-        key, segs, gids, params["nlist"], sys["kmeans_iters"]
-    )
-    nprobe = int(min(params["nprobe"], nlist))
-    cap = _ivf_cap(s, nlist, nprobe)
-    members = np.stack([_member_lists(assigns[z], nlist, cap) for z in range(n_seg)])
+    nprobe, cents, members = _build_ivf_common(key, segs, params, sys["kmeans_iters"])
     dsub = d // m
     if frozen is None:
         # shared codebooks across segments (trained on the pooled sample)
         pool = segs.reshape(-1, m, dsub)
         sample = pool[:: max(1, pool.shape[0] // 8192)]
         keys = jax.random.split(jax.random.fold_in(key, 7), m)
-        cb, _ = jax.vmap(
-            lambda kk, xs: kmeans_l2(kk, xs, c, sys["kmeans_iters"])
-        )(keys, jnp.asarray(sample.transpose(1, 0, 2)))  # (m, c, dsub)
-        cb = np.asarray(cb)
+        with obs.span("build.kmeans"):
+            cb, _ = jax.vmap(
+                lambda kk, xs: kmeans_l2(kk, xs, c, sys["kmeans_iters"])
+            )(keys, jnp.asarray(sample.transpose(1, 0, 2)))  # (m, c, dsub)
+            cb = np.asarray(cb)
     else:
         cb = np.asarray(frozen["codebooks"], np.float32)
     # encode: nearest codeword per subspace
-    codes = np.empty((n_seg, s, m), dtype=np.uint8)
-    x = segs.reshape(n_seg * s, m, dsub)
-    for j in range(m):
-        d2 = (
-            np.sum(x[:, j] ** 2, 1)[:, None]
-            - 2.0 * x[:, j] @ cb[j].T
-            + np.sum(cb[j] ** 2, 1)[None, :]
-        )
-        codes[..., j] = np.argmin(d2, axis=1).astype(np.uint8).reshape(n_seg, s)
-    return IndexBundle(
-        kind="IVF_PQ",
-        arrays={
+    with obs.span("build.encode"):
+        codes = np.empty((n_seg, s, m), dtype=np.uint8)
+        x = segs.reshape(n_seg * s, m, dsub)
+        for j in range(m):
+            d2 = (
+                np.sum(x[:, j] ** 2, 1)[:, None]
+                - 2.0 * x[:, j] @ cb[j].T
+                + np.sum(cb[j] ** 2, 1)[None, :]
+            )
+            codes[..., j] = np.argmin(d2, axis=1).astype(np.uint8).reshape(n_seg, s)
+    with obs.span("build.upload"):
+        arrays = {
             "codes": jnp.asarray(codes),
             "codebooks": jnp.asarray(cb.astype(np.float32)),
             "gids": jnp.asarray(gids),
             "centroids": jnp.asarray(cents),
             "members": jnp.asarray(members),
-        },
-        static={"nprobe": nprobe, "m": m, "c": c},
-    )
+        }
+    return IndexBundle(kind="IVF_PQ", arrays=arrays, static={"nprobe": nprobe, "m": m, "c": c})
 
 
 def _search_ivf_pq(q, arrays, *, k_seg: int, nprobe: int, m: int, c: int):
@@ -299,17 +299,7 @@ def _search_ivf_pq(q, arrays, *, k_seg: int, nprobe: int, m: int, c: int):
             lut[:, None, :, :], ccodes[..., None], axis=3
         )  # (B, P, m, 1)
         sims = jnp.sum(g[..., 0], axis=-1)
-        sims = jnp.where(cand >= 0, sims, -jnp.inf)
-        k = min(k_seg, sims.shape[1])
-        top_s, top_i = jax.lax.top_k(sims, k)
-        lids = jnp.take_along_axis(cand, top_i, axis=1)
-        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
-        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
-        if k < k_seg:
-            pad = k_seg - k
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-        return ids, top_s
+        return _segment_topk(sims, cand, gids, k_seg)
 
     return jax.lax.map(
         per_seg, (arrays["codes"], arrays["gids"], arrays["centroids"], arrays["members"])
@@ -470,30 +460,19 @@ def _search_hnsw(q, arrays, *, k_seg: int, ef: int, m_links: int):
 # SCANN — IVF + int8 score-aware quantized scan + exact re-ranking
 # =========================================================================
 def build_scann(key, segs, gids, params, sys, frozen=None) -> IndexBundle:
-    nlist, cents, assigns = _build_ivf_common(
-        key, segs, gids, params["nlist"], sys["kmeans_iters"]
-    )
-    nprobe = int(min(params["nprobe"], nlist))
-    cap = _ivf_cap(segs.shape[1], nlist, nprobe)
-    members = np.stack([_member_lists(assigns[z], nlist, cap) for z in range(len(segs))])
-    if frozen is None:
-        scale = np.abs(segs).max(axis=(0, 1)) / 127.0 + 1e-12
-    else:
-        scale = np.asarray(frozen["scale"], np.float32)
-    codes = np.clip(np.round(segs / scale), -127, 127).astype(np.int8)
+    nprobe, cents, members = _build_ivf_common(key, segs, params, sys["kmeans_iters"])
+    scale, codes = _sq8_encode(segs, frozen)
     reorder_k = int(max(params["reorder_k"], 1))
-    return IndexBundle(
-        kind="SCANN",
-        arrays={
+    with obs.span("build.upload"):
+        arrays = {
             "codes": jnp.asarray(codes),
-            "scale": jnp.asarray(scale.astype(np.float32)),
+            "scale": jnp.asarray(scale),
             "data": _storage(segs, sys["storage_bf16"]),
             "gids": jnp.asarray(gids),
             "centroids": jnp.asarray(cents),
             "members": jnp.asarray(members),
-        },
-        static={"nprobe": nprobe, "reorder_k": reorder_k},
-    )
+        }
+    return IndexBundle(kind="SCANN", arrays=arrays, static={"nprobe": nprobe, "reorder_k": reorder_k})
 
 
 def _search_scann(q, arrays, *, k_seg: int, nprobe: int, reorder_k: int):
@@ -508,21 +487,12 @@ def _search_scann(q, arrays, *, k_seg: int, nprobe: int, reorder_k: int):
         )
         approx = jnp.where(cand >= 0, approx, -jnp.inf)
         r = min(reorder_k, approx.shape[1])
-        _, top_r = jax.lax.top_k(approx, r)  # (B, r)
+        with obs.scope("segment_topk"):
+            _, top_r = jax.lax.top_k(approx, r)  # (B, r)
         rcand = jnp.take_along_axis(cand, top_r, axis=1)
         rsafe = jnp.maximum(rcand, 0)
         exact = jnp.einsum("brd,bd->br", data[rsafe].astype(jnp.float32), q)
-        exact = jnp.where(rcand >= 0, exact, -jnp.inf)
-        k = min(k_seg, exact.shape[1])
-        top_s, top_i = jax.lax.top_k(exact, k)
-        lids = jnp.take_along_axis(rcand, top_i, axis=1)
-        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
-        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
-        if k < k_seg:
-            pad = k_seg - k
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-        return ids, top_s
+        return _segment_topk(exact, rcand, gids, k_seg)
 
     return jax.lax.map(
         per_seg,

@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.space import Param
 from .fused import fused_search_ivf_pqr, shard_search_ivf_pqr
 from .indexes import (
@@ -28,6 +29,7 @@ from .indexes import (
     IndexBundle,
     _build_cost_ivf_pq,
     _gather_candidates,
+    _segment_topk,
     _storage,
     build_ivf_pq,
 )
@@ -60,21 +62,12 @@ def search_ivf_pqr(q, arrays, *, k_seg: int, nprobe: int, m: int, c: int, reorde
         approx = jnp.sum(g[..., 0], axis=-1)
         approx = jnp.where(cand >= 0, approx, -jnp.inf)
         r = min(reorder_k, approx.shape[1])
-        _, top_r = jax.lax.top_k(approx, r)  # (B, r)
+        with obs.scope("segment_topk"):
+            _, top_r = jax.lax.top_k(approx, r)  # (B, r)
         rcand = jnp.take_along_axis(cand, top_r, axis=1)
         rsafe = jnp.maximum(rcand, 0)
         exact = jnp.einsum("brd,bd->br", data[rsafe].astype(jnp.float32), q)
-        exact = jnp.where(rcand >= 0, exact, -jnp.inf)
-        k = min(k_seg, exact.shape[1])
-        top_s, top_i = jax.lax.top_k(exact, k)
-        lids = jnp.take_along_axis(rcand, top_i, axis=1)
-        ids = jnp.where(lids >= 0, gids[jnp.maximum(lids, 0)], -1)
-        top_s = jnp.where(ids >= 0, top_s, -jnp.inf)
-        if k < k_seg:
-            pad = k_seg - k
-            ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            top_s = jnp.pad(top_s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-        return ids, top_s
+        return _segment_topk(exact, rcand, gids, k_seg)
 
     return jax.lax.map(
         per_seg,

@@ -28,6 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import ops
 
 
@@ -36,12 +37,13 @@ def flatten_candidates(ids, sims, alive=None):
     (B, n_seg * k), optionally gating scores through the global ``alive``
     mask (id -1 hits the always-dead sentinel slot ``alive[-1]``)."""
     n_seg, b, ks = ids.shape
-    ids2 = jnp.moveaxis(ids, 0, 1).reshape(b, n_seg * ks)
-    sims2 = jnp.moveaxis(sims, 0, 1).reshape(b, n_seg * ks)
-    if alive is not None:
-        sentinel = alive.shape[0] - 1
-        ok = alive[jnp.where(ids2 >= 0, ids2, sentinel)]
-        sims2 = jnp.where(ok, sims2, -jnp.inf)
+    with obs.scope("merge"):
+        ids2 = jnp.moveaxis(ids, 0, 1).reshape(b, n_seg * ks)
+        sims2 = jnp.moveaxis(sims, 0, 1).reshape(b, n_seg * ks)
+        if alive is not None:
+            sentinel = alive.shape[0] - 1
+            ok = alive[jnp.where(ids2 >= 0, ids2, sentinel)]
+            sims2 = jnp.where(ok, sims2, -jnp.inf)
     return ids2, sims2
 
 
@@ -52,7 +54,8 @@ def partial_topk(ids, sims, k, alive=None):
     the full merge; prefiltering a flat list to its top-k preserves the
     global winners because at most ``k`` of them can come from one shard."""
     ids2, sims2 = flatten_candidates(ids, sims, alive=alive)
-    return ops.topk_by_score(ids2, sims2, min(k, sims2.shape[1]))
+    with obs.scope("merge"):
+        return ops.topk_by_score(ids2, sims2, min(k, sims2.shape[1]))
 
 
 def merge_flat(ids2, sims2, q, growing, growing_gids, topk, *, live: bool,
@@ -60,22 +63,23 @@ def merge_flat(ids2, sims2, q, growing, growing_gids, topk, *, live: bool,
     """Root of the merge: append the growing-tail candidates to flat
     per-query lists (B, W) and keep the global top-k. ``live`` selects the
     tombstone flavor (masked tail gids, -inf survivors become id -1)."""
-    if growing.shape[0] > 0:
-        gs = jnp.dot(q, growing.T.astype(q.dtype), preferred_element_type=jnp.float32)
+    with obs.scope("merge"):
+        if growing.shape[0] > 0:
+            gs = jnp.dot(q, growing.T.astype(q.dtype), preferred_element_type=jnp.float32)
+            if live:
+                gs = jnp.where(growing_gids[None, :] >= 0, gs, -jnp.inf)
+            gk = min(topk, growing.shape[0])
+            gtop_s, gtop_i = jax.lax.top_k(gs, gk)
+            ids2 = jnp.concatenate([ids2, growing_gids[gtop_i]], axis=1)
+            sims2 = jnp.concatenate([sims2, gtop_s], axis=1)
+        k = min(topk, sims2.shape[1])
+        out, top_s = ops.topk_by_score(ids2, sims2, k)
         if live:
-            gs = jnp.where(growing_gids[None, :] >= 0, gs, -jnp.inf)
-        gk = min(topk, growing.shape[0])
-        gtop_s, gtop_i = jax.lax.top_k(gs, gk)
-        ids2 = jnp.concatenate([ids2, growing_gids[gtop_i]], axis=1)
-        sims2 = jnp.concatenate([sims2, gtop_s], axis=1)
-    k = min(topk, sims2.shape[1])
-    out, top_s = ops.topk_by_score(ids2, sims2, k)
-    if live:
-        out = jnp.where(jnp.isfinite(top_s), out, -1)
-    if k < topk:
-        out = jnp.pad(out, ((0, 0), (0, topk - k)), constant_values=-1)
-        if return_scores:
-            top_s = jnp.pad(top_s, ((0, 0), (0, topk - k)), constant_values=-jnp.inf)
+            out = jnp.where(jnp.isfinite(top_s), out, -1)
+        if k < topk:
+            out = jnp.pad(out, ((0, 0), (0, topk - k)), constant_values=-1)
+            if return_scores:
+                top_s = jnp.pad(top_s, ((0, 0), (0, topk - k)), constant_values=-jnp.inf)
     if return_scores:
         return out, top_s
     return out

@@ -18,6 +18,8 @@ import dataclasses
 
 import numpy as np
 
+from .. import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class SegmentPlan:
@@ -79,12 +81,13 @@ def stack_sealed(data: np.ndarray, plan: SegmentPlan) -> tuple[np.ndarray, np.nd
     Returns (segments, global_ids); padded slots have id -1 and zero vectors.
     """
     s, d = plan.seg_size, data.shape[1]
-    segs = np.zeros((plan.n_sealed, s, d), dtype=data.dtype)
-    gids = -np.ones((plan.n_sealed, s), dtype=np.int32)
-    off = 0
-    for z in range(plan.n_sealed):
-        v = int(plan.sealed_valid[z])
-        segs[z, :v] = data[off : off + v]
-        gids[z, :v] = np.arange(off, off + v, dtype=np.int32)
-        off += v
+    with obs.span("build.stack_sealed"):
+        segs = np.zeros((plan.n_sealed, s, d), dtype=data.dtype)
+        gids = -np.ones((plan.n_sealed, s), dtype=np.int32)
+        off = 0
+        for z in range(plan.n_sealed):
+            v = int(plan.sealed_valid[z])
+            segs[z, :v] = data[off : off + v]
+            gids[z, :v] = np.arange(off, off + v, dtype=np.int32)
+            off += v
     return segs, gids

@@ -13,10 +13,7 @@ import numpy as np
 import pytest
 
 import repro.vdms as V
-from repro.vdms import engine
-from repro.vdms.ivf_pqr import register as register_ivf_pqr
-
-register_ivf_pqr()
+from repro.vdms import engine, ivf_pqr
 
 BASE = {
     "segment_max_size": 512, "seal_proportion": 0.75, "graceful_time": 0.2,
@@ -32,6 +29,18 @@ FALLBACK_CONFIGS = {
     "IVF_FLAT": {"nlist": 8, "nprobe": 4},
     "AUTOINDEX": {},
 }
+
+
+@pytest.fixture(autouse=True)
+def ivf_pqr_registered():
+    """IVF_PQR is registered on demand. Register it around each test, not at
+    import: another module's teardown on the same worker may unregister it
+    (``test_registry_conformance``'s ``extra_families`` does)."""
+    was_registered = ivf_pqr.FAMILY.name in V.registered_names()
+    ivf_pqr.register()
+    yield
+    if not was_registered:
+        V.unregister_family(ivf_pqr.FAMILY.name)
 
 
 @pytest.fixture
