@@ -34,18 +34,21 @@ from jax.experimental.pallas import tpu as pltpu
 from .fused_scan import (
     HIGHEST,
     _round_up,
+    flush_topk,
     merge_tile_topk,
     probe_and_init,
     probe_candidates,
     stacked_layout,
     stacked_spec,
     topk_candidates,
+    topk_outputs,
 )
 
 
 def _fused_pq_kernel(
     q_ref, c_ref, lut_ref, codes_ref, cl_ref, gid_ref, lid_out, sim_out,
-    cmask_scr, vals_scr, lids_scr, *, nlist, nprobe, k, m, cpad, n_steps, mask_dead,
+    pass_out, cmask_scr, vals_scr, lids_scr, passes_scr, *, nlist, nprobe, k, m, cpad, n_steps,
+    mask_dead,
 ):
     j = pl.program_id(2)
 
@@ -69,13 +72,13 @@ def _fused_pq_kernel(
 
     scores = jax.lax.fori_loop(0, m, body, jnp.zeros((bq, bn), jnp.float32))
     merge_tile_topk(
-        scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, k=k, mask_dead=mask_dead
+        scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, passes_scr, k=k,
+        mask_dead=mask_dead,
     )
 
     @pl.when(j == n_steps - 1)
     def _flush():
-        lid_out[...] = lids_scr[...]
-        sim_out[...] = vals_scr[...]
+        flush_topk(lid_out, sim_out, pass_out, vals_scr, lids_scr, passes_scr)
 
 
 @functools.partial(
@@ -98,8 +101,9 @@ def fused_ivf_pq_topk_pallas(
 ):
     """Stacked segments in one kernel: q (B, d) f32, lut (B, m, c) f32, codes
     (n_seg, s, m) integer, centroids (n_seg, nlist, d), cluster_of
-    (n_seg, s), gids (n_seg, s) -> (lids, sims) each (n_seg, B, k). Grid
-    ``(segment, query block, segment tile)`` as in the SQ8 kernel."""
+    (n_seg, s), gids (n_seg, s) -> (lids, sims) each (n_seg, B, k), and the
+    selection passes per (segment, query block). Grid ``(segment, query
+    block, segment tile)`` as in the SQ8 kernel."""
     b, d = q.shape
     _, m, c = lut.shape
     n_seg, s, _ = codes.shape
@@ -117,7 +121,7 @@ def fused_ivf_pq_topk_pallas(
     gp = jnp.pad(gids.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
     n_steps = np_ // bn
 
-    lids, sims = pl.pallas_call(
+    lids, sims, passes = pl.pallas_call(
         functools.partial(
             _fused_pq_kernel,
             nlist=nlist,
@@ -137,25 +141,13 @@ def fused_ivf_pq_topk_pallas(
             stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
             stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
         ],
-        out_specs=[
-            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
-            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.int32),
-            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, lp), jnp.float32),
-            pltpu.VMEM((bq, kp), jnp.float32),
-            pltpu.VMEM((bq, kp), jnp.int32),
-        ],
+        **topk_outputs(n_seg, bp, bq, lp, kp),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(qp, cp, lutp, codes_t, clp.reshape(n_seg, 1, np_), gp.reshape(n_seg, 1, np_))
-    return lids[:, :b, :k], sims[:, :b, :k]
+    return lids[:, :b, :k], sims[:, :b, :k], passes[:, :, 0, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -14,9 +14,14 @@ HBM. Two implementations share one contract:
   gather, so the candidate-list formulation is ADAPTED to a mask-scan: the
   probe runs in-kernel (iterative max-extraction into a cluster-mask VMEM
   scratch), each code tile is scored on the MXU against the resident query
-  block, cluster membership is applied as a one-hot matmul mask, and a
-  running top-k scratch is merged per tile by iterative argmax extraction
-  (``k`` selection steps over ``[running, tile]``).
+  block, cluster membership is applied as a one-hot matmul mask, and each
+  tile is folded into a running top-k scratch by threshold-gated selection
+  (:func:`merge_tile_topk`): only tile scores strictly above a row's k-th
+  running score can enter, so a tile runs as many passes as its most
+  demanding row needs, at most ``k`` and often none, each pass inserting a
+  row's best remaining tile score into the sorted running list behind every
+  entry at least as high (a tie goes to the lower local id). The kernel
+  also returns the passes it ran per (segment, query block).
 
 Memory-layout contract (shared by every fused kernel in this repo)
 ------------------------------------------------------------------
@@ -35,7 +40,9 @@ Memory-layout contract (shared by every fused kernel in this repo)
   of storage dtype; int8 codes are dequantized in-register per tile.
 * Outputs are (B, k) local ids (-1 = empty slot) + scores (-inf = empty);
   ordering among tied scores is implementation-defined — parity tests
-  compare score-sorted sets, not raw slot order.
+  compare score-sorted sets, not raw slot order. The Pallas kernels return
+  their lists sorted (score descending, then local id) and, third, the
+  selection passes of each (segment, query block), which ``ops`` drops.
 
 Candidate semantics match the composed path exactly: a point is a candidate
 iff it appears in the (capacity-bounded) member list of a probed cluster;
@@ -104,13 +111,29 @@ def probe_and_init(q_ref, c_ref, cmask_scr, vals_scr, lids_scr, *, nlist: int, n
 
 
 def merge_tile_topk(
-    scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, *, k: int, mask_dead: bool
+    scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, passes_scr, *, k: int,
+    mask_dead: bool,
 ):
     """Mask one scored tile by probed-cluster membership and fold it into the
-    running top-k scratch via ``k`` iterative argmax extractions (ties →
-    lowest slot). ``mask_dead`` additionally drops gid<0 slots pre-top-k (the
-    clamped static path); otherwise dead slots survive to the caller like the
-    composed path's post-top-k masking."""
+    running top-k scratch by threshold-gated selection.
+
+    The running list is kept sorted (score descending, ties by ascending local
+    id), so its slot ``k - 1`` is each row's admission threshold (-inf while
+    the list is not full). Only masked tile scores strictly above it can
+    enter, at most ``k`` of them, so the tile runs ``P = max over rows of
+    min(k, count above threshold)`` passes: ``P`` is 0 for a tile that cannot
+    change the result. Each pass extracts every row's best remaining tile
+    score (ties → lowest column; its local id is ``j * bn + column``) and
+    inserts it after every running entry that scores at least as high, the
+    entries behind it shifting one lane down; a score at or below the
+    threshold lands past slot ``k - 1`` and drops out of the result. So a tie
+    goes to the earlier slot, hence the lower local id, and the list equals
+    the first ``k`` of every candidate ordered by (score descending, local
+    id). ``P`` is added to ``passes_scr[0]``, which the first tile resets.
+
+    ``mask_dead`` additionally drops gid<0 slots pre-top-k (the clamped static
+    path); otherwise dead slots survive to the caller like the composed path's
+    post-top-k masking."""
     bn = scores.shape[1]
     cl = cl_ref[...]  # (1, bn) cluster id per point, -1 = not a candidate
     lp = cmask_scr.shape[1]
@@ -124,44 +147,37 @@ def merge_tile_topk(
     if mask_dead:
         ok = ok & (gid_ref[...] >= 0)
     scores = jnp.where(ok, scores, -jnp.inf)
-    lid_tile = j * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
 
-    vals = jnp.concatenate([vals_scr[...], scores], axis=1)
-    lids = jnp.concatenate([lids_scr[...], lid_tile], axis=1)
-    col = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    bp, kp = vals_scr.shape
-    slot = jax.lax.broadcasted_iota(jnp.int32, (bp, kp), 1)
+    vals, lids = vals_scr[...], lids_scr[...]
+    slot = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+    thr = jnp.min(jnp.where(slot < k, vals, jnp.inf), axis=1, keepdims=True)
+    above = jnp.sum((scores > thr).astype(jnp.int32), axis=1, keepdims=True)
+    n_pass = jnp.max(jnp.minimum(above, k))
 
-    def body(t, carry):
-        vals, out_v, out_l = carry
-        m = jnp.max(vals, axis=1, keepdims=True)
-        hit = (vals == m) & jnp.isfinite(m)
-        idx = jnp.min(jnp.where(hit, col, vals.shape[1]), axis=1, keepdims=True)
-        sel = col == idx
-        pick = jnp.sum(jnp.where(sel, lids, 0), axis=1, keepdims=True)
-        pick = jnp.where(jnp.isfinite(m), pick, -1).astype(jnp.int32)
-        # write slot t through a lane mask: Mosaic has no dynamic_update_slice
-        out_v = jnp.where(slot == t, m, out_v)
-        out_l = jnp.where(slot == t, pick, out_l)
-        vals = jnp.where(sel, -jnp.inf, vals)
-        return vals, out_v, out_l
+    def body(_, carry):
+        scores, vals, lids = carry
+        m = jnp.max(scores, axis=1, keepdims=True)
+        idx = jnp.min(jnp.where(scores == m, col, bn), axis=1, keepdims=True)
+        # m's place: after every entry >= m; kp (no change) for an exhausted row
+        pos = jnp.sum((vals >= m).astype(jnp.int32), axis=1, keepdims=True)
+        keep, here = slot < pos, slot == pos
+        vals = jnp.where(keep, vals, jnp.where(here, m, pltpu.roll(vals, 1, 1)))
+        lids = jnp.where(keep, lids, jnp.where(here, j * bn + idx, pltpu.roll(lids, 1, 1)))
+        return jnp.where(col == idx, -jnp.inf, scores), vals, lids
 
-    init = (
-        vals,
-        jnp.full((bp, kp), -jnp.inf, jnp.float32),
-        jnp.full((bp, kp), -1, jnp.int32),
-    )
-    _, out_v, out_l = jax.lax.fori_loop(0, k, body, init)
-    vals_scr[...] = out_v
-    lids_scr[...] = out_l
+    _, vals, lids = jax.lax.fori_loop(0, n_pass, body, (scores, vals, lids))
+    vals_scr[...] = vals
+    lids_scr[...] = lids
+    passes_scr[0] = jnp.where(j == 0, 0, passes_scr[0]) + n_pass
 
 
 # ---------------------------------------------------------------------------
 # SQ8 kernel
 # ---------------------------------------------------------------------------
 def _fused_sq8_kernel(
-    q_ref, c_ref, scale_ref, codes_ref, cl_ref, gid_ref, lid_out, sim_out,
-    cmask_scr, vals_scr, lids_scr, *, nlist, nprobe, k, n_steps, mask_dead,
+    q_ref, c_ref, scale_ref, codes_ref, cl_ref, gid_ref, lid_out, sim_out, pass_out,
+    cmask_scr, vals_scr, lids_scr, passes_scr, *, nlist, nprobe, k, n_steps, mask_dead,
 ):
     j = pl.program_id(2)
 
@@ -175,13 +191,20 @@ def _fused_sq8_kernel(
         precision=HIGHEST, preferred_element_type=jnp.float32,
     )  # (bq, bn)
     merge_tile_topk(
-        scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, k=k, mask_dead=mask_dead
+        scores, j, cl_ref, gid_ref, cmask_scr, vals_scr, lids_scr, passes_scr, k=k,
+        mask_dead=mask_dead,
     )
 
     @pl.when(j == n_steps - 1)
     def _flush():
-        lid_out[...] = lids_scr[...]
-        sim_out[...] = vals_scr[...]
+        flush_topk(lid_out, sim_out, pass_out, vals_scr, lids_scr, passes_scr)
+
+
+def flush_topk(lid_out, sim_out, pass_out, vals_scr, lids_scr, passes_scr):
+    """Last tile: write the running top-k and the block's selection-pass count."""
+    lid_out[...] = lids_scr[...]
+    sim_out[...] = vals_scr[...]
+    pass_out[...] = jnp.full(pass_out.shape, passes_scr[0], jnp.int32)
 
 
 def stacked_layout(b: int, s: int, nlist: int, k: int, bq: int, bn: int):
@@ -199,6 +222,32 @@ def stacked_spec(block, index_map):
     """BlockSpec of one stacked per-segment operand: the segment axis is
     squeezed out of the block and picked by grid axis 0."""
     return pl.BlockSpec((None,) + tuple(block), index_map)
+
+
+def topk_outputs(n_seg: int, bp: int, bq: int, lp: int, kp: int) -> dict:
+    """``pallas_call`` outputs and scratch shared by the fused kernels: the
+    running top-k (lids, sims), each (n_seg, bp, kp), and the selection
+    passes of each (segment, query block), one lane row (n_seg, bp // bq, 1,
+    128) apiece; scratch for the cluster mask, the running top-k and the
+    pass count."""
+    return dict(
+        out_specs=[
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
+            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
+            pl.BlockSpec((None, None, 1, 128), lambda z, i, j: (z, i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n_seg, bp // bq, 1, 128), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, lp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.float32),
+            pltpu.VMEM((bq, kp), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
 
 
 @functools.partial(
@@ -222,7 +271,8 @@ def fused_ivf_sq8_topk_pallas(
     """Stacked segments in one kernel: q (B, d) f32, codes (n_seg, s, d) int8,
     scale (d,), centroids (n_seg, nlist, d), cluster_of (n_seg, s) from
     :func:`members_to_cluster_of`, gids (n_seg, s) -> (lids, sims) each
-    (n_seg, B, k).
+    (n_seg, B, k), and the selection passes run per (segment, query block),
+    (n_seg, ceil(B / bq)) int32 (:func:`merge_tile_topk`).
 
     Grid ``(segment, query block, segment tile)``: one launch covers every
     segment, and VMEM holds one ``bq``-row query block, never the batch."""
@@ -239,7 +289,7 @@ def fused_ivf_sq8_topk_pallas(
     gp = jnp.pad(gids.astype(jnp.int32), ((0, 0), (0, np_ - s)), constant_values=-1)
     n_steps = np_ // bn
 
-    lids, sims = pl.pallas_call(
+    lids, sims, passes = pl.pallas_call(
         functools.partial(
             _fused_sq8_kernel,
             nlist=nlist,
@@ -257,25 +307,13 @@ def fused_ivf_sq8_topk_pallas(
             stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
             stacked_spec((1, bn), lambda z, i, j: (z, 0, j)),
         ],
-        out_specs=[
-            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
-            stacked_spec((bq, kp), lambda z, i, j: (z, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.int32),
-            jax.ShapeDtypeStruct((n_seg, bp, kp), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, lp), jnp.float32),
-            pltpu.VMEM((bq, kp), jnp.float32),
-            pltpu.VMEM((bq, kp), jnp.int32),
-        ],
+        **topk_outputs(n_seg, bp, bq, lp, kp),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(qp, cp, sp, codesp, clp.reshape(n_seg, 1, np_), gp.reshape(n_seg, 1, np_))
-    return lids[:, :b, :k], sims[:, :b, :k]
+    return lids[:, :b, :k], sims[:, :b, :k], passes[:, :, 0, 0]
 
 
 # ---------------------------------------------------------------------------

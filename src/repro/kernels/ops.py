@@ -108,10 +108,11 @@ def fused_ivf_sq8_topk(q, codes, scale, centroids, members, gids, *,
                 q, c, scale, ce, me, g, nprobe=nprobe, k=k, mask_dead=mask_dead
             )
         )(codes, centroids, members, gids)
-    return fused_ivf_sq8_topk_pallas(
+    lids, sims, _ = fused_ivf_sq8_topk_pallas(
         q, codes, scale, centroids, _cluster_of(members, codes.shape[1]), gids,
         nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=impl == "pallas_interpret",
     )
+    return lids, sims
 
 
 @partial(jax.jit, static_argnames=("nprobe", "k", "mask_dead", "impl"))
@@ -134,10 +135,11 @@ def fused_ivf_pq_topk(q, lut, codes, centroids, members, gids, *,
                 q, lut, c, ce, me, g, nprobe=nprobe, k=k, mask_dead=mask_dead
             )
         )(codes, centroids, members, gids)
-    return fused_ivf_pq_topk_pallas(
+    lids, sims, _ = fused_ivf_pq_topk_pallas(
         q, lut, codes, centroids, _cluster_of(members, codes.shape[1]), gids,
         nprobe=nprobe, k=k, mask_dead=mask_dead, interpret=impl == "pallas_interpret",
     )
+    return lids, sims
 
 
 @partial(jax.jit, static_argnames=("k", "impl"))

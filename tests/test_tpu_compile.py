@@ -86,6 +86,33 @@ def test_fused_pq_kernel_compiles(one_chip, m):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("family", ["sq8", "pq"])
+def test_fused_kernels_compile_at_widest_k(one_chip, family):
+    """The selection loop, whose trip count the scalar core reads from the
+    tile's scores, at k=128 (the widest ``topk_merge_width``: no padding
+    lanes in the running list); the tests above cover the default k=64."""
+    queries, lists = ((ROWS, D), jnp.float32), ((N_SEG, S), jnp.int32)
+    centroids = ((N_SEG, NLIST, D), jnp.float32)
+    if family == "sq8":
+        hlo = _compile(
+            one_chip,
+            lambda q, c, sc, ce, cl, g: fused_ivf_sq8_topk_pallas(
+                q, c, sc, ce, cl, g, nprobe=8, k=128
+            ),
+            queries, ((N_SEG, S, D), jnp.int8), ((D,), jnp.float32), centroids, lists, lists,
+        )
+    else:
+        hlo = _compile(
+            one_chip,
+            lambda q, lut, c, ce, cl, g: fused_ivf_pq_topk_pallas(
+                q, lut, c, ce, cl, g, nprobe=8, k=128
+            ),
+            queries, ((ROWS, 5, 256), jnp.float32), ((N_SEG, S, 5), jnp.uint8), centroids,
+            lists, lists,
+        )
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("storage", [jnp.float32, jnp.bfloat16])
 def test_distance_kernel_compiles(one_chip, storage):
     hlo = _compile(
