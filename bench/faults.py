@@ -16,10 +16,11 @@ turns ``correct`` false:
   ``half_corpus`` leaves half of the corpus out of the index; and, where a
   cell times builds (``BUILD_FAULTS``), ``no_kmeans`` builds with no k-means
   iteration, and ``stale_build`` does each build's work but returns the
-  first index it built, which is a build returning its state unchanged.
-
-(An exchange between chips does not exist in these cells: every cell has
-one chip.)
+  first index it built, which is a build returning its state unchanged;
+  on a configuration served on several shards (``SHARD_FAULTS``),
+  ``drop_shard`` answers from all shards but the last: its segments are
+  made dead, as the program's own padding segments are, which leaves out
+  the exchange with one chip of the mesh.
 """
 from __future__ import annotations
 
@@ -80,10 +81,10 @@ class Bf16Reference:
 def control(config: dict):
     """The control of a configuration: the program's own lower-precision
     path where it has one, else the reference in bfloat16."""
-    from bench.run import Program
+    from bench.run import Program, shards_of
 
     if config["index"]["index_type"] == "FLAT":
-        return Program({**config["index"], "storage_bf16": True})
+        return Program({**config["index"], "storage_bf16": True}, shards_of(config))
     return Bf16Reference(config["scoring"])
 
 
@@ -94,14 +95,19 @@ class Faulty:
         if fault not in FAULTS:
             raise ValueError(f"unknown fault {fault!r}; use one of {FAULTS}")
         if fault == "no_kmeans":
-            system = type(system)({**system.index_config, "kmeans_iters": 0})
+            system = type(system)({**system.index_config, "kmeans_iters": 0}, system.shards)
+        if fault in SHARD_FAULTS and system.shards < 2:
+            raise ValueError(f"{fault!r} needs a configuration on several shards")
         self.system, self.fault, self.first = system, fault, None
 
     def build(self, dataset, seed: int):
         n = dataset.n
         if self.fault == "half_corpus":
             dataset = dataclasses.replace(dataset, data=dataset.data[: n // 2])
-        built = _FaultySearcher(self.system.build(dataset, seed=seed), self.fault, n)
+        inner = self.system.build(dataset, seed=seed)
+        if self.fault == "drop_shard":
+            _kill_last_shard(inner.inner)
+        built = _FaultySearcher(inner, self.fault, n)
         if self.fault == "stale_build":
             self.first = self.first or built
             return self.first
@@ -124,5 +130,16 @@ class _FaultySearcher:
         return ids
 
 
+def _kill_last_shard(sharded) -> None:
+    """Make every segment of a ``ShardedVDMS``'s last shard dead in place:
+    id-like arrays -1, the others 0, each on its own sharding."""
+    per = sharded.per_shard
+    for name, v in sharded.arrays.items():
+        if name not in sharded.shared_arrays:
+            fill = -1 if name in ("gids", "members") else 0
+            sharded.arrays[name] = jax.device_put(v.at[v.shape[0] - per:].set(fill), v.sharding)
+
+
 BUILD_FAULTS = ("no_kmeans", "stale_build")
-FAULTS = ("half_batch", "altered_answer", "half_corpus", *BUILD_FAULTS)
+SHARD_FAULTS = ("drop_shard",)
+FAULTS = ("half_batch", "altered_answer", "half_corpus", *BUILD_FAULTS, *SHARD_FAULTS)

@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     run.require_chip(int(cell["chips"]))
-    program = run.Program(config["index"])
+    program = run.Program(config["index"], run.shards_of(config))
     system = {"program": program, "control": faults.control(config)}.get(args.system)
     system = system or faults.Faulty(program, args.system)
     for seed in seeds(args.seeds):

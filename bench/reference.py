@@ -5,12 +5,20 @@ sees the corpus and the queries (made by the benchmark from the seed), the
 configuration's stated semantics, and the ids the program answered:
 
 * exact top-k by inner product over the raw float32 corpus, computed on the
-  device at ``Precision.HIGHEST`` in blocks of corpus rows;
+  device at ``Precision.HIGHEST`` in blocks of corpus rows; over a corpus
+  sharded by rows (``corpus.make_corpus_sharded``), each device scans its own
+  rows under ``shard_map`` and the per-shard lists are merged, ties to the
+  lower id as on one device;
 * the score of any (query, id) pair in float64 on the host, either on the
   raw vector (``scoring: "f32"``) or on its SQ8 code (``scoring: "sq8"``:
   one shared per-dimension scale ``max|x| / 127``, codes ``round(x / scale)``
   clipped to +-127, dequantised as ``code * scale``), which is what IVF_SQ8
-  states it scores.
+  states it scores, the scale taken over the rows of its sealed segments
+  and only those rows scored so; the rows of the growing tail, which it
+  searches by brute force, score raw (``sealed_rows``). Over a sharded corpus
+  the per-dimension ``max|x|`` is
+  reduced across the devices and the rest done on the host, bit for bit
+  ``sq8_scale``.
 
 The numbers compared, each against a limit that the configuration file
 holds (``limits``) and ``PERF.md`` derives:
@@ -45,6 +53,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -71,8 +81,53 @@ def _exact_topk(corpus, queries, k: int, block: int):
     return ids, scores
 
 
-def exact_topk(corpus, queries, k: int, block: int = 32768) -> np.ndarray:
-    """Exact top-``k`` ids (rows of ``queries``) over the device corpus."""
+@partial(jax.jit, static_argnames=("k", "block", "n", "mesh"))
+def _sharded_topk(corpus, queries, k: int, block: int, n: int, mesh: Mesh):
+    per = corpus.shape[0] // mesh.size
+    n_blocks = -(-per // block)
+    b = queries.shape[0]
+
+    def local(rows, q):
+        first = jax.lax.axis_index("shard") * per
+
+        def step(carry, z):
+            best_s, best_i = carry
+            start = jnp.minimum(z * block, per - block)  # the last block overlaps the one before
+            x = jax.lax.dynamic_slice_in_dim(rows, start, block)
+            s = jnp.dot(q, x.T, precision=HIGHEST, preferred_element_type=jnp.float32)
+            row = start + jnp.arange(block)
+            s = jnp.where(((row >= z * block) & (first + row < n))[None, :], s, -jnp.inf)
+            top_s, top_pos = jax.lax.top_k(jnp.concatenate([best_s, s], axis=1), k)
+            kept = jnp.take_along_axis(best_i, jnp.minimum(top_pos, k - 1), axis=1)
+            ids = jnp.where(top_pos < k, kept, first + start + top_pos - k).astype(jnp.int32)
+            return (top_s, ids), None
+
+        init = (jnp.full((b, k), -jnp.inf, jnp.float32), jnp.full((b, k), -1, jnp.int32))
+        (scores, ids), _ = jax.lax.scan(step, init, jnp.arange(n_blocks))
+        return scores[None], ids[None]
+
+    parts_s, parts_i = jax.shard_map(local, mesh=mesh, in_specs=(P("shard"), P()),
+                                     out_specs=(P("shard"), P("shard")), check_vma=False)(corpus, queries)
+    # shard-major order: among equal scores the lower shard, whose ids are lower, comes first
+    scores, pos = jax.lax.top_k(jnp.moveaxis(parts_s, 0, 1).reshape(b, -1), k)
+    return jnp.take_along_axis(jnp.moveaxis(parts_i, 0, 1).reshape(b, -1), pos, axis=1), scores
+
+
+def sharded(corpus) -> bool:
+    """Whether a device corpus is spread over more than one device."""
+    sharding = getattr(corpus, "sharding", None)
+    return sharding is not None and len(sharding.device_set) > 1
+
+
+def exact_topk(corpus, queries, k: int, block: int = 32768, n: int | None = None) -> np.ndarray:
+    """Exact top-``k`` ids (rows of ``queries``) over the device corpus; of a
+    sharded corpus, over its first ``n`` rows."""
+    if sharded(corpus):
+        mesh = corpus.sharding.mesh
+        q = jax.device_put(np.asarray(queries, np.float32), NamedSharding(mesh, P()))
+        ids, _ = _sharded_topk(corpus, q, k, min(block, corpus.shape[0] // mesh.size),
+                               corpus.shape[0] if n is None else n, mesh)
+        return np.asarray(ids)
     ids, _ = _exact_topk(corpus, jnp.asarray(queries), k, min(block, corpus.shape[0]))
     return np.asarray(ids)
 
@@ -82,18 +137,46 @@ def sq8_scale(corpus: np.ndarray) -> np.ndarray:
     return (np.abs(corpus).max(axis=0) / np.float32(127.0) + np.float32(1e-12)).astype(np.float32)
 
 
+@jax.jit
+def _abs_max(corpus, rows):
+    first = jnp.arange(corpus.shape[0])[:, None] < rows
+    return jnp.where(first, jnp.abs(corpus), 0.0).max(axis=0)
+
+
+def sharded_sq8_scale(corpus, rows: int) -> np.ndarray:
+    """``sq8_scale`` of the first ``rows`` rows of a sharded device corpus, bit
+    for bit: the max reduced across the devices, then the division and the
+    sum in float32 on the host, as ``sq8_scale`` does them."""
+    return (np.asarray(_abs_max(corpus, rows)) / np.float32(127.0) + np.float32(1e-12)).astype(np.float32)
+
+
 def sq8_vectors(rows: np.ndarray, scale: np.ndarray) -> np.ndarray:
     """The SQ8 reading of corpus rows: codes dequantised, in float64."""
     codes = np.clip(np.round(rows / scale), -127, 127)
     return codes.astype(np.float64) * scale.astype(np.float64)
 
 
+def sealed_rows(n: int, segment_max_size: int, seal_proportion: float) -> int:
+    """Corpus rows in sealed segments, as the system parameters state them:
+    whole segments of ``segment_max_size`` rows (at least 64), and the last,
+    part-filled one where it holds ``seal_proportion`` of that or more. The
+    rows after them are the growing tail."""
+    s = min(max(int(segment_max_size), 64), n)
+    full, rem = divmod(n, s)
+    if full == 0:
+        return n  # all of it one sealed segment
+    return full * s + (rem if rem > 0 and rem >= seal_proportion * s else 0)
+
+
 def pair_scores(corpus: np.ndarray, queries: np.ndarray, ids: np.ndarray, scoring: str,
-                scale: np.ndarray | None = None) -> np.ndarray:
-    """float64 score of each (query row, id) pair; ids (m, k) into ``corpus``."""
+                scale: np.ndarray | None = None, raw_from: int | None = None) -> np.ndarray:
+    """float64 score of each (query row, id) pair; ids (m, k) into ``corpus``.
+    Under ``sq8`` an id from ``raw_from`` on (the growing tail) scores raw."""
     rows = corpus[np.clip(ids, 0, corpus.shape[0] - 1)]  # (m, k, d)
     if scoring == "sq8":
         vecs = sq8_vectors(rows, sq8_scale(corpus) if scale is None else scale)
+        if raw_from is not None and raw_from < corpus.shape[0]:
+            vecs = np.where((ids >= raw_from)[..., None], rows.astype(np.float64), vecs)
     elif scoring == "f32":
         vecs = rows.astype(np.float64)
     else:
@@ -115,28 +198,36 @@ def bad_rows(answers: np.ndarray, n: int) -> np.ndarray:
 
 
 def compare(answers: np.ndarray, pool: np.ndarray, rows: np.ndarray, corpus_host: np.ndarray,
-            corpus_device, scoring: str, exact: bool, chunk: int = 2048) -> dict:
-    """The numbers compared for ``answers`` (m, k) to the pool rows ``rows`` (m,)."""
+            corpus_device, scoring: str, exact: bool, chunk: int = 2048,
+            raw_from: int | None = None) -> dict:
+    """The numbers compared for ``answers`` (m, k) to the pool rows ``rows`` (m,).
+    ``corpus_device`` holds ``corpus_host`` on the device: as it is, or sharded
+    by rows with zero rows past them (``corpus.make_corpus_sharded``).
+    ``raw_from``: the first row of the growing tail (``sealed_rows``), where
+    the SQ8 scale ends and raw scores begin."""
     answers, rows = np.asarray(answers), np.asarray(rows)
     n, k = corpus_host.shape[0], answers.shape[1]
     bad = bad_rows(answers, n)
     unique, where = np.unique(rows, return_inverse=True)
     truth = np.concatenate([  # exact top-k of each distinct query, in fixed-size chunks
-        exact_topk(corpus_device, np.resize(pool[unique[i : i + chunk]], (chunk, pool.shape[1])), k)
+        exact_topk(corpus_device, np.resize(pool[unique[i : i + chunk]], (chunk, pool.shape[1])), k, n=n)
         [: min(chunk, unique.size - i)]
         for i in range(0, unique.size, chunk)
     ])[where]
     hits = (answers[:, :, None] == truth[:, None, :]).any(axis=2).sum()
     queries = pool[rows]
-    scale = sq8_scale(corpus_host) if scoring == "sq8" else None
-    got = pair_scores(corpus_host, queries, answers, scoring, scale)
+    scale, sealed = None, n if raw_from is None else raw_from
+    if scoring == "sq8":
+        scale = (sharded_sq8_scale(corpus_device, sealed) if sharded(corpus_device)
+                 else sq8_scale(corpus_host[:sealed]))
+    got = pair_scores(corpus_host, queries, answers, scoring, scale, raw_from)
     numbers = {
         "bad_ids": int(bad.sum()),
         "recall_loss": 1.0 - hits / answers.size,
         "order_gap": order_gap(got[~bad]) if (~bad).any() else float("inf"),
     }
     if exact:
-        kth = pair_scores(corpus_host, queries, truth[:, -1:], scoring, scale)[:, 0]
+        kth = pair_scores(corpus_host, queries, truth[:, -1:], scoring, scale, raw_from)[:, 0]
         short = np.where((answers < 0) | (answers >= n), np.inf, kth[:, None] - got)
         numbers["rank_gap"] = float(max(short.max(), 0.0))
     return numbers

@@ -10,6 +10,16 @@ and warms the one shape the traffic uses; then one window of ``--seconds``
 runs with no compilation in it. After the window the answers are compared
 with the plain reference (``bench/reference.py``).
 
+A configuration that states ``"shards": N`` (1 where it states none) is served
+on N chips by ``repro.vdms.ShardedVDMS`` under ``shard_map``; its cell asks for
+N chips. Set-up makes its corpus across those chips a chunk at a time and
+copies each chunk to the host for the program, so that the chips hold none of
+it when the program builds; the check makes it again there after the window.
+The device record and the trace cover the cell's chips (``bench/trace.py``):
+``memory_peak_bytes`` is the fullest chip's peak, and
+``memory_data_peak_bytes_per_device`` each chip's peak before the program
+builds, the most of it that can be the harness's data.
+
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` traces the
 window and reports its per-layer metrics, each read by
 ``bench/metrics/<name>.py`` (or, for a name ``<base>.<split>``, by
@@ -68,7 +78,25 @@ def load_cell(name: str, spec: dict) -> tuple[dict, dict, dict]:
     cell = cells[name]
     config = json.loads((BENCH / "configs" / f"{cell['config']}.json").read_text())
     mix = traffic.validate(json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text()))
-    return cell, config, mix
+    return cell, validate_cell(cell, config, mix), mix
+
+
+def shards_of(config: dict) -> int:
+    """The chips a configuration is served on: its ``shards``, 1 where it states none."""
+    return int(config.get("shards", 1))
+
+
+def validate_cell(cell: dict, config: dict, mix: dict) -> dict:
+    """Refuse a cell whose chips are not its configuration's shards, or that
+    the harness cannot check; returns the configuration."""
+    shards = shards_of(config)
+    if shards < 1 or int(cell["chips"]) != shards:
+        raise ValueError(f"cell {cell['name']!r} asks for {cell['chips']} chips; its configuration "
+                         f"{config['name']!r} is served on {shards} shards")
+    if shards > 1 and mix["op"] == "build":
+        raise ValueError(f"cell {cell['name']!r}: a build cell cannot run on {shards} shards, "
+                         "because the build check's k-means reference holds the corpus on one device")
+    return config
 
 
 def cell_metrics(spec: dict, cell: str, per_layer: bool) -> list:
@@ -100,15 +128,28 @@ def reader(name: str):
 # the system under test
 # ---------------------------------------------------------------------------
 class Program:
-    """The program's static engine under one index configuration."""
+    """The program's static engine under one index configuration, on ``shards`` chips.
 
-    def __init__(self, index_config: dict):
-        self.index_config = dict(index_config)
+    What the harness relies on, and a change to which changes the benchmark:
+
+    * one shard: ``VDMSInstance(dataset, index_config, seed=seed)``, whose
+      ``search(queries, topk)`` returns the ids (queries, topk);
+    * N shards: ``ShardedVDMS(dataset, index_config, n_shards=N,
+      dispatch="shard_map", seed=seed)`` over the first N devices, whose
+      ``search(queries, topk, mode="wall")`` returns ``(ids, elapsed)``, of
+      which ``ShardedSearcher`` keeps the ids.
+    """
+
+    def __init__(self, index_config: dict, shards: int = 1):
+        self.index_config, self.shards = dict(index_config), int(shards)
 
     def build(self, dataset, seed: int):
-        from repro.vdms import VDMSInstance
+        from repro.vdms import ShardedVDMS, VDMSInstance
 
-        return VDMSInstance(dataset, self.index_config, seed=seed)
+        if self.shards == 1:
+            return VDMSInstance(dataset, self.index_config, seed=seed)
+        return ShardedSearcher(ShardedVDMS(dataset, self.index_config, n_shards=self.shards,
+                                           dispatch="shard_map", seed=seed))
 
     @staticmethod
     def clusters(built):
@@ -116,6 +157,17 @@ class Program:
         the corpus row in each slot of each segment."""
         arrays = built.bundle.arrays
         return arrays["centroids"], arrays["gids"]
+
+
+class ShardedSearcher:
+    """A ``ShardedVDMS`` under the searcher contract of the traffic: ids only."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def search(self, queries, topk: int) -> np.ndarray:
+        ids, _ = self.inner.search(queries, topk, mode="wall")
+        return ids
 
 
 def instrument() -> None:
@@ -177,13 +229,13 @@ class Tracer:
         self.span = None
         jax.profiler.stop_trace()
 
-    def finish(self):
+    def finish(self, chips: int):
         from bench import trace as trace_mod
 
         if self.span is not None:
             self._stop()
         try:
-            return trace_mod.load(self.dir)
+            return trace_mod.load(self.dir, chips)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -191,17 +243,51 @@ class Tracer:
 # ---------------------------------------------------------------------------
 # one run
 # ---------------------------------------------------------------------------
+def make_data(config: dict, seed: int):
+    """(corpus, pool) of a configuration on the device, from the seed: on one
+    device, or across the configuration's shards (``bench/corpus.py``)."""
+    from bench import corpus
+
+    shape, shards = config["shape"], shards_of(config)
+    n, dim, n_pool = int(shape["n"]), int(shape["dim"]), int(shape["queries"])
+    if shards == 1:
+        return corpus.make_corpus(seed, n, n_pool, dim)
+    return corpus.make_corpus_sharded(seed, n, n_pool, dim, corpus.shard_mesh(shards))
+
+
+def host_data(config: dict, seed: int):
+    """Set-up's data: (corpus, pool, corpus on the host, pool on the host),
+    the first two on the device or None. On one device both stay there: the
+    corpus for the check, the pool while set-up holds it. Across shards the
+    corpus is made and copied to the host a chunk at a time, and the devices
+    keep none of it: the check makes it again (``make_data``)."""
+    from bench import corpus
+
+    shape, shards = config["shape"], shards_of(config)
+    if shards == 1:
+        rows, pool = make_data(config, seed)
+        return rows, pool, np.asarray(rows), np.asarray(pool)
+    rows_host, pool_host = corpus.host_corpus_sharded(seed, int(shape["n"]), int(shape["queries"]),
+                                                      int(shape["dim"]), corpus.shard_mesh(shards))
+    return None, None, rows_host, pool_host
+
+
+def peak_bytes(chips: int) -> list:
+    """``peak_bytes_in_use`` of each of the first ``chips`` devices so far."""
+    import jax
+
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.devices()[:chips]]
+
+
 def set_up(config: dict, mix: dict, seed: int, system=None) -> types.SimpleNamespace:
     """Corpus and pool from the seed, the index built, the traffic's shape warm."""
     from bench import traffic
-    from bench.corpus import make_corpus
 
-    shape = config["shape"]
-    n, dim, k, n_pool = int(shape["n"]), int(shape["dim"]), int(shape["k"]), int(shape["queries"])
-    system = Program(config["index"]) if system is None else system
+    k, shards = int(config["shape"]["k"]), shards_of(config)
+    system = Program(config["index"], shards) if system is None else system
     instrument()
-    corpus, pool = make_corpus(seed, n, n_pool, dim)
-    corpus_host, pool_host = np.asarray(corpus), np.asarray(pool)
+    corpus, pool, corpus_host, pool_host = host_data(config, seed)  # pool: on one device, freed on return
+    data_peaks = peak_bytes(shards)  # what of each chip's peak the harness's data may be
     dataset = dataset_of(corpus_host, pool_host, k)
     searcher = system.build(dataset, seed=0)
     warm_clusters = None
@@ -211,7 +297,7 @@ def set_up(config: dict, mix: dict, seed: int, system=None) -> types.SimpleNames
     traffic.warm(mix, system=system, searcher=searcher, dataset=dataset, pool=pool_host)
     return types.SimpleNamespace(corpus=corpus, corpus_host=corpus_host, pool=pool_host,
                                  dataset=dataset, system=system, searcher=searcher, k=k,
-                                 warm_clusters=warm_clusters)
+                                 warm_clusters=warm_clusters, data_peaks=data_peaks)
 
 
 def answered(stage, mix: dict, log, seed: int) -> types.SimpleNamespace:
@@ -236,10 +322,13 @@ def check(stage, config: dict, seed: int, ev) -> dict:
     """The numbers compared, from ``answered``'s evidence."""
     from bench import reference
 
-    numbers = reference.compare(ev.answers, stage.pool, ev.rows, stage.corpus_host, stage.corpus,
-                                scoring=config["scoring"], exact=bool(config["exact"]))
+    index = config["index"]
+    corpus = stage.corpus if stage.corpus is not None else make_data(config, seed)[0]
+    tail = reference.sealed_rows(stage.corpus_host.shape[0], int(index["segment_max_size"]),
+                                 float(index["seal_proportion"]))
+    numbers = reference.compare(ev.answers, stage.pool, ev.rows, stage.corpus_host, corpus,
+                                scoring=config["scoring"], exact=bool(config["exact"]), raw_from=tail)
     if hasattr(ev, "kept"):
-        index = config["index"]
         numbers["repeated_builds"] = len(ev.prints) - len(set(ev.prints))
         numbers["kmeans_gap"] = float("inf") if ev.kept is None else (
             reference.lloyd_objective(stage.corpus, int(index["segment_max_size"]),
@@ -278,12 +367,14 @@ def run_cell(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float, 
     finally:
         monitoring.unregister_event_duration_listener(on_event)
     in_window = sum(w0 <= t <= w1 for t in compiles)
-    tr = tracer.finish() if trace else None
+    chips = int(cell["chips"])
+    tr = tracer.finish(chips) if trace else None
 
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
+    peaks, dev = peak_bytes(chips), jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count(),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+              "memory_peak_bytes": None if None in peaks else max(peaks),  # the fullest chip
+              "memory_peak_bytes_per_device": peaks,
+              "memory_data_peak_bytes_per_device": stage.data_peaks}
     ctx = types.SimpleNamespace(cell=cell, config=config, mix=mix, log=log, trace=tr,
                                 setup_s=setup_s, searcher=stage.searcher, pool=stage.pool,
                                 device_kind=dev.device_kind, say=say)
@@ -295,8 +386,9 @@ def run_cell(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float, 
     breakdown = None
     if tr is not None:
         a, b = tr.window()
-        device["busy_s"] = tr.busy.covered(a, b)
+        device["busy_s"] = tr.busy.covered(a, b)  # the mean over the cell's chips
         device["window_s"] = b - a
+        device["busy_s_per_device"] = [t.covered(a, b) for t in tr.busy_per_device]
         breakdown = {"device_ops": trace_mod.top_ops(tr, a, b),
                      "idle_gaps": trace_mod.idle_by_activity(tr, a, b)}
         calls = [s for s in tr.spans if s[0] in ("search_call", "build")]

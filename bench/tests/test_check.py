@@ -3,22 +3,28 @@ and each planted fault that the cell can have turn ``correct`` false.
 
 Each case drives the rest of a run (set-up, the window of the cell's own
 traffic, the check) with the look for a chip skipped, at 20,000 x 100 with
-1,000 pool queries and the cell's own limits.
+1,000 pool queries and the cell's own limits. A cell served on several shards
+runs in a child process with as many CPU devices (``multichip.py``).
 """
 import copy
 
 import pytest
 
 from bench import faults, run
+from bench.tests import multichip
 
 SPEC = run.load_spec()
 CELLS = [c["name"] for c in SPEC["workloads"]]
-CELL_FAULTS = [
-    (cell, fault)
-    for cell in CELLS
-    for fault in faults.FAULTS
-    if run.load_cell(cell, SPEC)[2]["op"] == "build" or fault not in faults.BUILD_FAULTS
-]
+
+
+def can_have(cell, fault):
+    _, config, mix = run.load_cell(cell, SPEC)
+    if fault in faults.SHARD_FAULTS:
+        return run.shards_of(config) > 1
+    return mix["op"] == "build" or fault not in faults.BUILD_FAULTS
+
+
+CELL_FAULTS = [(cell, fault) for cell in CELLS for fault in faults.FAULTS if can_have(cell, fault)]
 
 
 def tiny(cell):
@@ -33,11 +39,28 @@ def tiny(cell):
     return entry, config, mix
 
 
-def run_tiny(cell, system_of=None, seconds=1.0):
+def system_of(name, config):
+    """The system of a case by its name: ``program`` (the program itself),
+    ``control``, or a planted fault of ``bench/faults.py``."""
+    if name == "program":
+        return None
+    if name == "control":
+        return faults.control(config)
+    return faults.Faulty(run.Program(config["index"], run.shards_of(config)), name)
+
+
+def run_here(cell, system="program", seconds=1.0):
     entry, config, mix = tiny(cell)
-    system = None if system_of is None else system_of(config)
     return run.run_cell(entry, config, mix, seed=2**32 + 17, seconds=seconds, trace=False,
-                        metrics=run.cell_metrics(SPEC, cell, per_layer=False), system=system)
+                        metrics=run.cell_metrics(SPEC, cell, per_layer=False),
+                        system=system_of(system, config))
+
+
+def run_tiny(cell, system="program", seconds=1.0):
+    shards = run.shards_of(run.load_cell(cell, SPEC)[1])
+    if shards > 1:
+        return multichip.call(shards, "bench.tests.test_check:run_here", cell, system, seconds)
+    return run_here(cell, system, seconds)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -50,11 +73,11 @@ def test_program_is_correct(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_is_not_correct(cell):
-    result = run_tiny(cell, faults.control)
+    result = run_tiny(cell, "control")
     assert not result["correct"], result["check"]
 
 
 @pytest.mark.parametrize(("cell", "fault"), CELL_FAULTS)
 def test_fault_is_not_correct(cell, fault):
-    result = run_tiny(cell, lambda config: faults.Faulty(run.Program(config["index"]), fault))
+    result = run_tiny(cell, fault)
     assert not result["correct"], result["check"]

@@ -16,6 +16,7 @@ def test_cell_loads(cell):
     entry, config, mix = run.load_cell(cell, SPEC)
     assert entry["config"] == config["name"]
     assert entry["chips"] in (1, 4)
+    assert config.get("shards", 1) == entry["chips"]
     assert config["scoring"] in ("f32", "sq8")
     assert set(config["limits"]) >= {"bad_ids", "recall_loss", "order_gap"}
     assert config["limits"]["bad_ids"] == 0

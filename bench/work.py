@@ -22,6 +22,13 @@ from a kernel's grid, so it reads the same work whatever implements it:
 * FLAT: ``2 * B * n * d`` FLOPs; the float32 corpus read once and the
   float32 queries.
 
+On a cell of several chips the same rule holds with one chip's peaks: the
+summed least time of the calls' required work at one chip's peak, over the
+kernel's device time summed over the cell's devices (``Trace.timeline`` of
+each device). The required work counts the index as placed, less the dead
+padding segments that even out the shards: they hold no row. On one chip the
+sum has one term, and this is the rule above.
+
 Peaks are keyed by the ``device_kind`` that JAX reports (``peaks.json``); a
 device that is not in the table is an error.
 """
